@@ -19,7 +19,9 @@ from repro.api import DiLiClient, LocalBackend
 from repro.core.balancer import Balancer
 from repro.core.oracle import OracleList
 from repro.core.types import DiLiConfig, OP_FIND, OP_INSERT, OP_REMOVE
+from repro.jax_cache import enable_compile_cache
 
+enable_compile_cache()
 cfg = DiLiConfig(num_shards=4, pool_capacity=8192, max_sublists=64,
                  max_ctrs=64, max_scan=8192, batch_size=32,
                  mailbox_cap=256, split_threshold=50, move_batch=16)

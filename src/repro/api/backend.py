@@ -311,8 +311,8 @@ class ShardMapBackend:
             if devs.size < cfg.num_shards:
                 raise ValueError(
                     f"need {cfg.num_shards} devices for {cfg.num_shards} "
-                    f"shards, have {devs.size} (set "
-                    f"--xla_force_host_platform_device_count)")
+                    f"shards, found {devs.size} "
+                    f"{jax.default_backend()} device(s)")
             mesh = Mesh(devs[:cfg.num_shards].reshape(cfg.num_shards),
                         ("shard",))
         self.mesh = mesh

@@ -9,9 +9,6 @@ Each device of the (flattened) mesh is one DiLi shard ("server"). A round is:
   2. bucket the outbox by destination shard,
   3. one ``all_to_all`` — the paper's RPC fabric. ≤2 collective hops per
      client op (≤3 during a Switch) is exactly Theorem 4's delegation bound.
-
-This is the module the multi-pod dry-run lowers for the ``dili-service``
-architecture: the production mesh's devices become 256/512 DiLi servers.
 """
 from __future__ import annotations
 
@@ -21,7 +18,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from . import bg as B
 from . import messages as M
@@ -127,12 +123,12 @@ def make_dili_round(mesh: Mesh, cfg: DiLiConfig, cap_pair: int = 8):
 
     pspec = P(axes)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(pspec, pspec, pspec, pspec),
         out_specs=(pspec, pspec, pspec, pspec, pspec, pspec, pspec,
                    pspec, pspec),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(fn)
 
 
@@ -181,12 +177,12 @@ def make_dili_round_hostroute(mesh: Mesh, cfg: DiLiConfig):
                 out.ent_hits[None])
 
     pspec = P(axes)
-    fn = shard_map(
+    fn = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(pspec, pspec, pspec, pspec),
         out_specs=(pspec, pspec, pspec, pspec, pspec, pspec, pspec,
                    pspec, pspec),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(fn)
 
 

@@ -160,6 +160,35 @@ def chain_keys(cfg: DiLiConfig, states: Sequence[ShardState], s: int,
     return out
 
 
+def chain_sizes(cfg: DiLiConfig, states: Sequence[ShardState], s: int,
+                heads: np.ndarray) -> np.ndarray:
+    """``len(chain_keys(cfg, states, s, h))`` for every subhead ``h`` in
+    ``heads``, all chains walked in lock step — one vectorized step per
+    chain position instead of one Python step per node, which is what lets
+    the balancer inspect a store of a million keys every pass."""
+    st = states[s]
+    nxt = np.asarray(st.pool.nxt).astype(np.int64)
+    key = np.asarray(st.pool.key)
+    size = np.zeros(len(heads), np.int64)
+    lane = np.arange(len(heads))            # chains still being walked
+    ref = nxt[np.asarray(heads, np.int64)]
+    for _ in range(int(cfg.pool_capacity) + 2):
+        idx = ref & refs.IDX_MASK
+        sid = (ref & refs.SID_MASK) >> refs.IDX_BITS
+        go = (idx != refs.NULL_IDX) & (sid == s)
+        k = key[np.where(go, idx, 0)]
+        go &= k != ST_KEY
+        lane, idx, k = lane[go], idx[go], k[go]
+        if not len(lane):
+            return size
+        ref = nxt[idx]
+        size[lane] += (k != SH_KEY) & ((ref & refs.MARK_BIT) == 0)
+    raise RuntimeError(
+        f"shard {s}: a chain did not terminate within "
+        f"pool_capacity={int(cfg.pool_capacity)} steps "
+        f"— cyclic or corrupted chain")
+
+
 def state_sublists(cfg: DiLiConfig, states: Sequence[ShardState], s: int):
     """(keymin, keymax, owner, size, head_idx, switched) per entry of
     shard s's registry replica; ``size`` is None for entries owned
@@ -167,23 +196,21 @@ def state_sublists(cfg: DiLiConfig, states: Sequence[ShardState], s: int):
     switched away (stCt < 0) — a stale local copy awaiting quarantine."""
     st = states[s]
     reg = st.registry
-    out = []
-    for e in range(int(reg.size)):
-        sh = int(np.asarray(reg.subhead)[e])
-        sid = (sh & refs.SID_MASK) >> refs.IDX_BITS
-        head_idx = sh & refs.IDX_MASK
-        size = None
-        switched = False
-        if sid == s:
-            size = len(chain_keys(cfg, states, s, head_idx))
-            slot = int(np.asarray(st.pool.ctr)[head_idx])
-            switched = int(np.asarray(st.stct)[slot]) < 0
-        out.append(dict(
-            keymin=int(np.asarray(reg.keymin)[e]),
-            keymax=int(np.asarray(reg.keymax)[e]),
-            owner=int(sid), size=size, head_idx=int(head_idx),
-            switched=switched))
-    return out
+    n = int(reg.size)
+    sh = np.asarray(reg.subhead)[:n].astype(np.int64)
+    sid = (sh & refs.SID_MASK) >> refs.IDX_BITS
+    head = sh & refs.IDX_MASK
+    own = sid == s
+    size = np.zeros(n, np.int64)
+    size[own] = chain_sizes(cfg, states, s, head[own])
+    switched = np.zeros(n, bool)
+    switched[own] = np.asarray(st.stct)[np.asarray(st.pool.ctr)[head[own]]] < 0
+    kmin = np.asarray(reg.keymin)[:n].tolist()
+    kmax = np.asarray(reg.keymax)[:n].tolist()
+    return [dict(keymin=kmin[e], keymax=kmax[e], owner=int(sid[e]),
+                 size=int(size[e]) if own[e] else None,
+                 head_idx=int(head[e]), switched=bool(switched[e]))
+            for e in range(n)]
 
 
 def global_keys(cfg: DiLiConfig, states: Sequence[ShardState]) -> List[int]:

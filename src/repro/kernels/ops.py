@@ -43,9 +43,10 @@ def _default_interpret() -> bool:
 
 def hybrid_search(keymin, blocks, queries, *, tile_q: int = 128,
                   interpret: bool | None = None):
-    """Batched DiLi lookup (registry binary search + block sweep).
+    """Batched DiLi lookup (registry search + block sweep).
 
-    Contract: real keys are strictly below ``INT32_MAX`` — that value is
+    Contract: ``keymin`` is sorted (the registry's invariant), and real
+    keys are strictly below ``INT32_MAX`` — that value is
     the block/registry padding sentinel, so a query of ``INT32_MAX`` would
     compare equal to every padding cell and report a spurious hit. Such
     queries are masked here: their ``found`` is always False (their
@@ -57,7 +58,7 @@ def hybrid_search(keymin, blocks, queries, *, tile_q: int = 128,
         interpret = _default_interpret()
     slot, found = _hybrid_search(keymin, blocks, queries, tile_q=tile_q,
                                  interpret=interpret)
-    return slot, found & (queries != _INT32_MAX)
+    return slot, (found != 0) & (queries != _INT32_MAX)
 
 
 def paged_attention(q, k_pages, v_pages, page_table, seq_lens, *,

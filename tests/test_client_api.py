@@ -255,6 +255,7 @@ def test_backend_parity_local_vs_shard_map():
     identical linearized results and final key sets."""
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    env["JAX_PLATFORMS"] = "cpu"
     env.pop("XLA_FLAGS", None)
     r = subprocess.run([sys.executable, "-c", PARITY_SCRIPT], env=env,
                        capture_output=True, text=True, timeout=600,

@@ -90,6 +90,7 @@ SCRIPT = textwrap.dedent("""
 def test_shard_map_backend_matches_oracle():
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    env["JAX_PLATFORMS"] = "cpu"
     env.pop("XLA_FLAGS", None)
     r = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                        capture_output=True, text=True, timeout=600,
@@ -97,3 +98,29 @@ def test_shard_map_backend_matches_oracle():
                            os.path.abspath(__file__))))
     assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr}"
     assert "OK" in r.stdout
+
+
+def test_import_is_free_of_deprecation_warnings():
+    """The SPMD backend uses the installed JAX's own ``jax.shard_map``."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = "src"
+    env["JAX_PLATFORMS"] = "cpu"
+    r = subprocess.run([sys.executable, "-W", "error::DeprecationWarning",
+                        "-c", "import repro.core.distributed"], env=env,
+                       capture_output=True, text=True, timeout=300,
+                       cwd=os.path.dirname(os.path.dirname(
+                           os.path.abspath(__file__))))
+    assert r.returncode == 0, r.stderr
+
+
+def test_shard_map_backend_names_the_devices_it_found():
+    import jax
+
+    from repro.api import ShardMapBackend
+    from repro.core.types import DiLiConfig
+    n = len(jax.devices())
+    cfg = DiLiConfig(num_shards=n + 1, pool_capacity=256, max_sublists=8,
+                     max_ctrs=8, max_scan=256, batch_size=8)
+    with pytest.raises(ValueError, match=f"found {n} "
+                       f"{jax.default_backend()} device"):
+        ShardMapBackend(cfg)

@@ -63,6 +63,7 @@ def test_restore_onto_different_mesh(tmp_path):
 
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    env["JAX_PLATFORMS"] = "cpu"
     env.pop("XLA_FLAGS", None)
     r = subprocess.run([sys.executable, "-c", SCRIPT, path], env=env,
                        capture_output=True, text=True, timeout=600,

@@ -157,6 +157,7 @@ def test_range_differential_shardmap():
     subprocess because the device count must be set before jax loads."""
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    env["JAX_PLATFORMS"] = "cpu"
     env.pop("XLA_FLAGS", None)
     r = subprocess.run([sys.executable, "-c", SHARDMAP_SCRIPT], env=env,
                        capture_output=True, text=True, timeout=900,
@@ -206,6 +207,7 @@ def test_guards_survive_python_O():
     not asserts — they must fire under ``python -O``."""
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-O", "-c", OPT_SCRIPT],
                        env=env, capture_output=True, text=True,
                        timeout=600,

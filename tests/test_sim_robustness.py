@@ -13,6 +13,7 @@ R4  Op ids are int32 message lanes: completed ids drained through
 R5  ``shard_chain`` raises on a cyclic/corrupted chain instead of
     returning a silent prefix (which made ``all_keys()``-based
     assertions pass vacuously).
+R6  The balancer's vectorized sublist sizes equal the per-node chain walk.
 """
 import numpy as np
 import pytest
@@ -20,7 +21,7 @@ import pytest
 from repro.core import refs
 from repro.core.oracle import OracleList
 from repro.core.sim import Cluster, OutboxOverflow
-from repro.core.types import DiLiConfig, OP_FIND, OP_INSERT
+from repro.core.types import DiLiConfig, OP_FIND, OP_INSERT, OP_REMOVE
 
 CFG = DiLiConfig(num_shards=2, pool_capacity=2048, max_sublists=16,
                  max_ctrs=16, max_scan=2048, batch_size=16,
@@ -136,3 +137,39 @@ def test_shard_chain_cycle_raises():
         cl.shard_chain(0, 0)
     with pytest.raises(RuntimeError, match="did not terminate"):
         cl.all_keys()
+
+
+def test_sublist_sizes_match_chain_walks():
+    """R6: the lock-step ``chain_sizes`` the balancer reads agrees with a
+    per-node ``chain_keys`` walk on every entry — mid-churn (marked nodes
+    not yet delinked, splits and moves in flight) and once quiet."""
+    from repro.core.balancer import Balancer
+    from repro.core.sim import chain_keys, state_sublists
+
+    cfg = CFG._replace(split_threshold=12)
+    cl = Cluster(cfg)
+    bal = Balancer(cl)
+    rng = np.random.default_rng(0)
+    keys = rng.choice(np.arange(1, 4000), 300, replace=False).tolist()
+    cl.submit(0, [OP_INSERT] * len(keys), keys)
+    cl.submit(1, [OP_REMOVE] * 100, keys[::3])
+
+    def check():
+        for s in range(cfg.num_shards):
+            for e in state_sublists(cfg, cl.states, s):
+                if e["owner"] == s:
+                    assert e["size"] == len(
+                        chain_keys(cfg, cl.states, s, e["head_idx"]))
+                else:
+                    assert e["size"] is None
+
+    for r in range(60):
+        cl.step()
+        if r % 3 == 0:
+            bal.step()
+            check()
+    cl.run_until_quiet(2000)
+    check()
+    assert sum(e["size"] or 0 for s in range(cfg.num_shards)
+               for e in state_sublists(cfg, cl.states, s)
+               if not e["switched"]) == len(cl.all_keys())
