@@ -35,12 +35,15 @@ from repro.core import messages as M
 from repro.core import range_scan as RS
 from repro.core import refs
 from repro.core import replica as R
+from repro.core.distributed import ROUTED_HOPS, ROUTED_LIVE, ROUTED_OPS
 from repro.core.membership import (Membership, epoch_row, moves_targeting,
                                    owned_entry_count)
+from repro.core.shard import CTR, add_counters
 from repro.core.sim import (Cluster, OpIdAllocator, OutboxOverflow,
                             chain_keys, global_keys, make_op_row,
                             materialize_ops, registry_entries,
                             state_sublists)
+from repro.core.spans import span
 from repro.core.types import (DiLiConfig, KEY_MAX, KEY_MIN, SH_KEY,
                               ST_KEY)
 
@@ -169,19 +172,20 @@ class LocalBackend:
         ``Cluster.step`` overwrites) so tools stepping the cluster
         directly between backend rounds cannot orphan client futures."""
         self.cluster.step()
-        comps = []
-        done = [op_id for op_id in self._issued
-                if op_id in self.cluster.results]
-        for op_id in done:
-            src = self.cluster.result_src.get(op_id, -1)
-            if op_id in self._range_issued:
-                # pull the scan items before take_result purges them
-                self._range_items[op_id] = \
-                    self.cluster.take_range_items(op_id)
-                self._range_issued.discard(op_id)
-            val = self.cluster.take_result(op_id)   # pops + recycles the id
-            self._issued.discard(op_id)
-            comps.append((op_id, val, src))
+        with span("cluster.harvest"):
+            comps = []
+            done = [op_id for op_id in self._issued
+                    if op_id in self.cluster.results]
+            for op_id in done:
+                src = self.cluster.result_src.get(op_id, -1)
+                if op_id in self._range_issued:
+                    # pull the scan items before take_result purges them
+                    self._range_items[op_id] = \
+                        self.cluster.take_range_items(op_id)
+                    self._range_issued.discard(op_id)
+                val = self.cluster.take_result(op_id)  # pops, recycles id
+                self._issued.discard(op_id)
+                comps.append((op_id, val, src))
         return comps
 
     @property
@@ -396,7 +400,7 @@ class ShardMapBackend:
         self.stats = {"max_outbox": 0, "max_hops": 0, "rounds": 0,
                       "fast_hits": 0, "mut_hits": 0, "delegated": 0,
                       "move_hits": 0, "blk_hits": 0, "max_bg_active": 0,
-                      "rep_hits": 0, "range_hits": 0}
+                      "rep_hits": 0, "range_hits": 0, "serial_rows": 0}
         # RANGE reassembly (DESIGN.md §16) — same count-gated protocol
         # as ``Cluster``: items and the terminal count ride separate
         # completion rows (and, across shards, separate transport lanes),
@@ -687,87 +691,84 @@ class ShardMapBackend:
         """One round on the nemesis path: device round (no all_to_all),
         host-side transport routing of the raw outboxes."""
         from repro.core.net import trace_entry
-        cfg = self.cfg
-        if self._crash_plans:
-            self._apply_crash_plans()
-        down = self.net.down
-        client = self._feed_client(down)
-        inbox = np.zeros((self.n, self.in_cap, M.FIELDS), np.int32)
-        for s in range(self.n):
-            feed = self._net_backlog[s][:self.in_cap]
-            self._net_backlog[s] = self._net_backlog[s][self.in_cap:]
-            inbox[s, :feed.shape[0]] = feed
-        out = self._rnd(self._states, self._bgs,
-                        self._jnp.asarray(inbox),
-                        self._jnp.asarray(client))
-        self._states, self._bgs, outbox, cs, cv, cr, ck, rstats, \
-            ent_hits = out
-        self._host_states = None
-        rstats = np.asarray(rstats)
-        out_counts = [int(c) for c in rstats[:, 0]]
-        self._check_overflow(out_counts)
-        self.stats["max_bg_active"] = max(self.stats["max_bg_active"],
-                                          int(rstats[:, 1].max()))
-        self.stats["move_hits"] += int(rstats[:, 2].sum())
-        self.stats["fast_hits"] += int(rstats[:, 3].sum())
-        self.stats["mut_hits"] += int(rstats[:, 4].sum())
-        self.stats["blk_hits"] += int(rstats[:, 5].sum())
-        self.stats["rep_hits"] += int(rstats[:, 6].sum())
-        self.stats["range_hits"] += int(rstats[:, 7].sum())
-        self._update_op_rates(ent_hits, rstats[:, 6])
-        outbox = np.asarray(outbox)
-        per_src = []
-        for s in range(self.n):
-            rows = outbox[s][:out_counts[s]]
-            hops = rows[rows[:, M.F_KIND] == M.MSG_OP, M.F_X2]
-            if hops.size:
-                self.stats["max_hops"] = max(self.stats["max_hops"],
-                                             int(hops.max()))
-                self.stats["delegated"] += int(hops.size)
-            per_src.append((s, rows))
-        pre_lens = [b.shape[0] for b in self._net_backlog]
-        self.net.route_round(self._net_backlog, per_src, self.round_no)
-        comps = self._harvest(cs, cv, cr, ck)
-        self._membership_maintenance()
-        if self.durability is not None:
-            # journal per live shard (same record layout as Cluster.step):
-            # the client feed consumed, the routed appends, completions +
-            # bg phases + epoch (replay audit), post-routing lane image.
-            cs_h = np.asarray(cs)
-            cv_h, cr_h = np.asarray(cv), np.asarray(cr)
-            ck_h = np.asarray(ck)
-            phases = np.asarray(self._bgs.phase)
-            epochs = np.asarray(self._states.epoch)
+        with span("shardmap.launch"):
+            if self._crash_plans:
+                self._apply_crash_plans()
+            down = self.net.down
+            client = self._feed_client(down)
+            inbox = np.zeros((self.n, self.in_cap, M.FIELDS), np.int32)
             for s in range(self.n):
-                if s in down:
-                    continue
-                done = cs_h[s] >= 0
-                comp = np.stack([cs_h[s][done], cv_h[s][done],
-                                 cr_h[s][done], ck_h[s][done]],
-                                axis=1).astype(np.int32)
-                lanes = self.net.export_shard_lanes(s)
-                self.durability.log_round(
-                    s, self.round_no,
-                    appends=self._net_backlog[s][pre_lens[s]:],
-                    client=client[s], comp=comp, bg_phases=phases[s],
-                    epoch=int(epochs[s]), lanes=lanes)
-                if (self.durability.config.snapshot_every > 0
-                        and (self.round_no + 1)
-                        % self.durability.config.snapshot_every == 0):
-                    st = self._jax.tree_util.tree_map(
-                        lambda x, s=s: np.asarray(x)[s], self._states)
-                    bg = self._jax.tree_util.tree_map(
-                        lambda x, s=s: np.asarray(x)[s], self._bgs)
-                    self.durability.snapshot_now(
-                        s, self.round_no, st, bg, self._net_backlog[s],
-                        lanes)
-        for ep, ev, sh in self.membership.log[self._mb_logged:]:
-            self.round_trace.append(f"r{self.round_no} mb {ev} s{sh} e{ep}")
-        self._mb_logged = len(self.membership.log)
-        self.round_trace.append(trace_entry(
-            self.round_no, comps, out_counts,
-            extra=sum(b.shape[0] for b in self._net_backlog)
-            + self.net.in_flight()))
+                feed = self._net_backlog[s][:self.in_cap]
+                self._net_backlog[s] = self._net_backlog[s][self.in_cap:]
+                inbox[s, :feed.shape[0]] = feed
+            out = self._rnd(self._states, self._bgs,
+                            self._jnp.asarray(inbox),
+                            self._jnp.asarray(client))
+            self._states, self._bgs, outbox, cs, cv, cr, ck, ctr, \
+                ent_hits = out
+            self._host_states = None
+        with span("shardmap.harvest"):
+            ctr = np.asarray(ctr)
+            out_counts = [int(c) for c in ctr[:, CTR["out_count"]]]
+            self._check_overflow(out_counts)
+            add_counters(self.stats, ctr)
+            self._update_op_rates(ent_hits, ctr[:, CTR["rep_hits"]])
+            outbox = np.asarray(outbox)
+            per_src = []
+            for s in range(self.n):
+                rows = outbox[s][:out_counts[s]]
+                hops = rows[rows[:, M.F_KIND] == M.MSG_OP, M.F_X2]
+                if hops.size:
+                    self.stats["max_hops"] = max(self.stats["max_hops"],
+                                                 int(hops.max()))
+                    self.stats["delegated"] += int(hops.size)
+                per_src.append((s, rows))
+            comps = self._harvest(cs, cv, cr, ck)
+        with span("shardmap.route"):
+            pre_lens = [b.shape[0] for b in self._net_backlog]
+            self.net.route_round(self._net_backlog, per_src, self.round_no)
+            self._membership_maintenance()
+            if self.durability is not None:
+                # journal per live shard (same record layout as
+                # Cluster.step): the client feed consumed, the routed
+                # appends, completions + bg phases + epoch (replay audit),
+                # post-routing lane image.
+                cs_h = np.asarray(cs)
+                cv_h, cr_h = np.asarray(cv), np.asarray(cr)
+                ck_h = np.asarray(ck)
+                phases = np.asarray(self._bgs.phase)
+                epochs = np.asarray(self._states.epoch)
+                for s in range(self.n):
+                    if s in down:
+                        continue
+                    done = cs_h[s] >= 0
+                    comp = np.stack([cs_h[s][done], cv_h[s][done],
+                                     cr_h[s][done], ck_h[s][done]],
+                                    axis=1).astype(np.int32)
+                    lanes = self.net.export_shard_lanes(s)
+                    self.durability.log_round(
+                        s, self.round_no,
+                        appends=self._net_backlog[s][pre_lens[s]:],
+                        client=client[s], comp=comp, bg_phases=phases[s],
+                        epoch=int(epochs[s]), lanes=lanes)
+                    if (self.durability.config.snapshot_every > 0
+                            and (self.round_no + 1)
+                            % self.durability.config.snapshot_every == 0):
+                        st = self._jax.tree_util.tree_map(
+                            lambda x, s=s: np.asarray(x)[s], self._states)
+                        bg = self._jax.tree_util.tree_map(
+                            lambda x, s=s: np.asarray(x)[s], self._bgs)
+                        self.durability.snapshot_now(
+                            s, self.round_no, st, bg, self._net_backlog[s],
+                            lanes)
+            for ep, ev, sh in self.membership.log[self._mb_logged:]:
+                self.round_trace.append(
+                    f"r{self.round_no} mb {ev} s{sh} e{ep}")
+            self._mb_logged = len(self.membership.log)
+            self.round_trace.append(trace_entry(
+                self.round_no, comps, out_counts,
+                extra=sum(b.shape[0] for b in self._net_backlog)
+                + self.net.in_flight()))
         self.round_no += 1
         self.stats["rounds"] += 1
         return comps
@@ -775,33 +776,29 @@ class ShardMapBackend:
     def step(self) -> List[Completion]:
         if self.net is not None:
             return self._step_hostroute()
-        cfg = self.cfg
-        client = self._feed_client()
-        out = self._rnd(self._states, self._bgs, self._inbox,
-                        self._jnp.asarray(client))
-        self._states, self._bgs, self._inbox, cs, cv, cr, ck, rstats, \
-            ent_hits = out
-        self._host_states = None
-        # per-shard int32[9] round stats computed on-device (the routed
-        # inbox itself never crosses to host on the hot path; see
-        # make_dili_round's docstring for the lane layout)
-        rstats = np.asarray(rstats)
-        self._check_overflow([int(c) for c in rstats[:, 0]])
-        self._inflight_msgs = int(rstats[:, 1].sum())
-        self.stats["max_bg_active"] = max(self.stats["max_bg_active"],
-                                          int(rstats[:, 4].max()))
-        self.stats["move_hits"] += int(rstats[:, 5].sum())
-        self.stats["blk_hits"] += int(rstats[:, 6].sum())
-        self.stats["rep_hits"] += int(rstats[:, 7].sum())
-        self.stats["range_hits"] += int(rstats[:, 8].sum())
-        self._update_op_rates(ent_hits, rstats[:, 7])
-        delegated = int(rstats[:, 2].sum())
-        if delegated:
-            self.stats["delegated"] += delegated
-            self.stats["max_hops"] = max(self.stats["max_hops"],
-                                         int(rstats[:, 3].max()))
-        comps = self._harvest(cs, cv, cr, ck)
-        self._membership_maintenance()
+        with span("shardmap.launch"):
+            client = self._feed_client()
+            out = self._rnd(self._states, self._bgs, self._inbox,
+                            self._jnp.asarray(client))
+            self._states, self._bgs, self._inbox, cs, cv, cr, ck, ctr, \
+                ent_hits = out
+            self._host_states = None
+        with span("shardmap.harvest"):
+            # per-shard round counters computed on-device, the routed
+            # inbox's after the shard's own (the inbox itself never
+            # crosses to host on the hot path; see make_dili_round)
+            ctr = np.asarray(ctr)
+            self._check_overflow([int(c) for c in ctr[:, CTR["out_count"]]])
+            add_counters(self.stats, ctr)
+            self._inflight_msgs = int(ctr[:, ROUTED_LIVE].sum())
+            self._update_op_rates(ent_hits, ctr[:, CTR["rep_hits"]])
+            delegated = int(ctr[:, ROUTED_OPS].sum())
+            if delegated:
+                self.stats["delegated"] += delegated
+                self.stats["max_hops"] = max(self.stats["max_hops"],
+                                             int(ctr[:, ROUTED_HOPS].max()))
+            comps = self._harvest(cs, cv, cr, ck)
+            self._membership_maintenance()
         self.round_no += 1
         self.stats["rounds"] += 1
         return comps

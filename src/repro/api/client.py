@@ -43,6 +43,7 @@ from collections import deque
 from itertools import islice
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
+from repro.core.spans import span
 from repro.core.types import OP_FIND, OP_INSERT, OP_REMOVE
 
 from .backend import Backend, LocalBackend
@@ -118,6 +119,10 @@ class DiLiClient:
         self._refresh_from: Optional[int] = None     # pending cache refresh
         self._rounds = 0
         self.wrong_routes = 0                        # completions off-route
+        # op-rounds spent queued in the client (per-key order, pacing
+        # budget, per-shard feed bound): the queue's length after each
+        # admission, summed
+        self.queue_rounds = 0
         # replica routing (§15): {keymax: (keymin, primary, [replicas])}
         # plus the sorted keymax index for range lookup; re-pulled whenever
         # the backend's replica_epoch moves.
@@ -237,65 +242,72 @@ class DiLiClient:
     def pump(self, run_balance: bool = True) -> int:
         """One round: refresh-route, admit, execute, harvest. Returns the
         number of futures resolved this round."""
-        mb = getattr(self.backend, "membership", None)
-        if mb is not None and mb.epoch != self._seen_epoch:
-            # membership changed (DESIGN.md §13): re-aim the home shard if
-            # it left, recompute the pacing budget against the new live
-            # count (unless the caller pinned it), and refresh the route
-            # cache so draining shards stop receiving fresh ops promptly
-            # (stale routes would still be *safe* — just slower to heal).
-            self._seen_epoch = mb.epoch
-            if not mb.is_routable(self.home_shard):
-                self.home_shard = min(mb.active)
-            if not self._pinned_inflight:
-                self.max_inflight = self._auto_inflight()
-            if self.route_cache:
-                self._refresh_from = self.home_shard
-        if self._refresh_from is not None and self.route_cache:
-            self.refresh_route_cache(self._refresh_from)
-        rep_epoch = getattr(self.backend, "replica_epoch", 0)
-        if rep_epoch != self._seen_replica_epoch:
-            self._seen_replica_epoch = rep_epoch
-            self._replica_sets = dict(self.backend.replica_sets())
-            self._replica_maxs = sorted(self._replica_sets)
-        self._admit()
-        ndone = 0
-        for op_id, val, src in self.backend.step():
-            fut = self._inflight.pop(op_id, None)
-            if fut is None:
-                # backends only report ops issued through them, and a
-                # backend supports one driving client — unreachable unless
-                # two clients share a backend (unsupported)
-                continue
-            if isinstance(fut, RangeResult):
-                # the completion value is the item count (or error code);
-                # the pairs are fetched once from the backend. The src
-                # shard is whichever served the *last* segment — not a
-                # routing signal, so no wrong-route refresh for scans.
-                fut._resolve(val, src, self.backend.take_range_items(op_id))
+        with span("client.admit"):
+            mb = getattr(self.backend, "membership", None)
+            if mb is not None and mb.epoch != self._seen_epoch:
+                # membership changed (DESIGN.md §13): re-aim the home
+                # shard if it left, recompute the pacing budget against the
+                # new live count (unless the caller pinned it), and refresh
+                # the route cache so draining shards stop receiving fresh
+                # ops promptly (stale routes would still be *safe* — just
+                # slower to heal).
+                self._seen_epoch = mb.epoch
+                if not mb.is_routable(self.home_shard):
+                    self.home_shard = min(mb.active)
+                if not self._pinned_inflight:
+                    self.max_inflight = self._auto_inflight()
+                if self.route_cache:
+                    self._refresh_from = self.home_shard
+            if self._refresh_from is not None and self.route_cache:
+                self.refresh_route_cache(self._refresh_from)
+            rep_epoch = getattr(self.backend, "replica_epoch", 0)
+            if rep_epoch != self._seen_replica_epoch:
+                self._seen_replica_epoch = rep_epoch
+                self._replica_sets = dict(self.backend.replica_sets())
+                self._replica_maxs = sorted(self._replica_sets)
+            self._admit()
+            self.queue_rounds += len(self._queue)
+        comps = self.backend.step()
+        with span("client.resolve"):
+            ndone = 0
+            for op_id, val, src in comps:
+                fut = self._inflight.pop(op_id, None)
+                if fut is None:
+                    # backends only report ops issued through them, and a
+                    # backend supports one driving client — unreachable
+                    # unless two clients share a backend (unsupported)
+                    continue
+                if isinstance(fut, RangeResult):
+                    # the completion value is the item count (or error
+                    # code); the pairs are fetched once from the backend.
+                    # The src shard is whichever served the *last* segment
+                    # — not a routing signal, so no wrong-route refresh.
+                    fut._resolve(val, src,
+                                 self.backend.take_range_items(op_id))
+                    fut.op_id = None
+                    self._scan_spans.pop(op_id, None)
+                    ndone += 1
+                    continue
+                fut._resolve(val, src)
                 fut.op_id = None
-                self._scan_spans.pop(op_id, None)
-                ndone += 1
-                continue
-            fut._resolve(val, src)
-            fut.op_id = None
-            if fut.kind == OP_FIND:
-                left = self._find_out.get(fut.key, 1) - 1
-                if left > 0:
-                    self._find_out[fut.key] = left
+                if fut.kind == OP_FIND:
+                    left = self._find_out.get(fut.key, 1) - 1
+                    if left > 0:
+                        self._find_out[fut.key] = left
+                    else:
+                        self._find_out.pop(fut.key, None)
                 else:
-                    self._find_out.pop(fut.key, None)
-            else:
-                self._busy_mut.discard(fut.key)
-            ndone += 1
-            if src != fut.shard and not getattr(fut, "via_replica", False):
-                # wrong-route reply: the executing shard's replica covers
-                # this key freshest — refresh from it next pump. FINDs
-                # deliberately aimed at read replicas (or bounced home by
-                # an expired one) are not routing errors and don't
-                # trigger refresh churn.
-                self.wrong_routes += 1
-                self._refresh_from = src
+                    self._busy_mut.discard(fut.key)
+                ndone += 1
+                if (src != fut.shard
+                        and not getattr(fut, "via_replica", False)):
+                    # wrong-route reply: the executing shard's replica
+                    # covers this key freshest — refresh from it next pump.
+                    # FINDs deliberately aimed at read replicas (or bounced
+                    # home by an expired one) are not routing errors and
+                    # don't trigger refresh churn.
+                    self.wrong_routes += 1
+                    self._refresh_from = src
         self._rounds += 1
         if (run_balance and self.balance is not None
                 and self._rounds % self.balance_every == 0):
@@ -464,7 +476,8 @@ class DiLiClient:
     # ------------------------------------------------------------ inspection
     @property
     def stats(self) -> Dict[str, int]:
-        return self.backend.stats
+        """The backend's counters and the client's ``queue_rounds``."""
+        return {**self.backend.stats, "queue_rounds": self.queue_rounds}
 
     def all_keys(self) -> List[int]:
         return self.backend.all_keys()

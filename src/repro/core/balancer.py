@@ -39,6 +39,7 @@ from __future__ import annotations
 from typing import Dict, Optional, Protocol
 
 from . import bg as B
+from .spans import span
 
 
 class BalancePolicy(Protocol):
@@ -99,249 +100,254 @@ class Balancer:
 
     def step(self) -> dict:
         """One balancing pass; returns counts of issued commands."""
-        cl = self.cl
-        issued = {"split": 0, "move": 0, "merge": 0, "evacuate": 0,
-                  "replicate": 0, "drop": 0}
-        # membership view (DESIGN.md §13): sources of load are every
-        # routable shard, valid destinations for new moves are
-        # active+joining, and draining shards get force-evacuated below.
-        # A membership-less cluster (raw duck-typed surface) balances over
-        # all shards, exactly as before.
-        mb = getattr(cl, "membership", None)
-        if mb is None:
-            routable = targets = list(range(cl.n))
-            draining = []
-        else:
-            routable = list(mb.routable)
-            targets = list(mb.targets)
-            draining = list(mb.draining)
-        owned = {s: self._owned(s) for s in routable}
-        # per-entry effective load: op-rate EWMA (keyed by keymax, pulled
-        # off the backend) weighted on top of the key count
-        rates = getattr(cl, "op_rate_ewma", None) or {}
+        with span("balancer.inspect"):
+            cl = self.cl
+            issued = {"split": 0, "move": 0, "merge": 0, "evacuate": 0,
+                      "replicate": 0, "drop": 0}
+            # membership view (DESIGN.md §13): sources of load are every
+            # routable shard, valid destinations for new moves are
+            # active+joining, and draining shards get force-evacuated below.
+            # A membership-less cluster (raw duck-typed surface) balances over
+            # all shards, exactly as before.
+            mb = getattr(cl, "membership", None)
+            if mb is None:
+                routable = targets = list(range(cl.n))
+                draining = []
+            else:
+                routable = list(mb.routable)
+                targets = list(mb.targets)
+                draining = list(mb.draining)
+            owned = {s: self._owned(s) for s in routable}
+            # per-entry effective load: op-rate EWMA (keyed by keymax, pulled
+            # off the backend) weighted on top of the key count
+            rates = getattr(cl, "op_rate_ewma", None) or {}
 
-        # read replication (§15): the current replica map, and whether the
-        # backend supports replication at all (raw duck-typed surfaces
-        # without the command are balanced exactly as before)
-        rep_on = (getattr(cl.cfg, "replication", False)
-                  and hasattr(cl, "replica_sets"))
-        repsets = cl.replica_sets() if rep_on else {}
+            # read replication (§15): the current replica map, and whether the
+            # backend supports replication at all (raw duck-typed surfaces
+            # without the command are balanced exactly as before)
+            rep_on = (getattr(cl.cfg, "replication", False)
+                      and hasattr(cl, "replica_sets"))
+            repsets = cl.replica_sets() if rep_on else {}
 
-        def eload(e):
-            r = rates.get(e["keymax"], 0.0)
-            rs = repsets.get(e["keymax"])
-            if rs:
-                # the entry rate is cluster-wide (replica shards bump the
-                # same global registry entry when they serve), but the
-                # client spreads reads round-robin over primary+replicas —
-                # charge the owner only its share, or the primary looks
-                # crushed by load it isn't serving and the balancer churns
-                # moves it can never satisfy (the hot entry is pinned).
-                # Serving shards are charged via rep_rate_ewma below.
-                r /= 1 + len(rs[2])
-            return e["size"] + self.rate_weight * r
+            def eload(e):
+                r = rates.get(e["keymax"], 0.0)
+                rs = repsets.get(e["keymax"])
+                if rs:
+                    # the entry rate is cluster-wide (replica shards bump
+                    # the same global registry entry when they serve), but
+                    # the client spreads reads round-robin over
+                    # primary+replicas — charge the owner only its share,
+                    # or the primary looks crushed by load it isn't serving
+                    # and the balancer churns moves it can never satisfy
+                    # (the hot entry is pinned). Serving shards are charged
+                    # via rep_rate_ewma below.
+                    r /= 1 + len(rs[2])
+                return e["size"] + self.rate_weight * r
 
-        def shed_replicas(s, kmax):
-            """True when ``kmax`` is replicated: its replicas are told to
-            drop and the caller must skip restructuring it this pass —
-            Move/Split/Merge on a replicated entry first retires the
-            replicas (the primary's session self-audit is only the safety
-            net for races, not the clean path)."""
-            if kmax not in repsets:
-                return False
-            if cl.drop_replica(s, kmax):
-                issued["drop"] += 1
-            del repsets[kmax]
-            return True
+            def shed_replicas(s, kmax):
+                """True when ``kmax`` is replicated: its replicas are told to
+                drop and the caller must skip restructuring it this pass —
+                Move/Split/Merge on a replicated entry first retires the
+                replicas (the primary's session self-audit is only the safety
+                net for races, not the clean path)."""
+                if kmax not in repsets:
+                    return False
+                if cl.drop_replica(s, kmax):
+                    issued["drop"] += 1
+                del repsets[kmax]
+                return True
 
-        loads = {s: sum(eload(e) for e in owned[s]) for s in routable}
-        # replica service is real load on the serving shard but invisible
-        # to the registry-keyed entry rates (the entry lives on the
-        # primary): fold each shard's replica-served FIND EWMA in, or the
-        # model reads serving replicas as idle and churns moves (and
-        # `shed_replicas` teardowns) against phantom imbalance.
-        rep_rates = getattr(cl, "rep_rate_ewma", None) or {}
-        for s in routable:
-            loads[s] += self.rate_weight * rep_rates.get(s, 0.0)
-        total = sum(loads.values())
-        # the mean the policy steers toward is over the shards that will
-        # still hold data after the drains complete
-        mean = total / max(len(targets), 1)
+            loads = {s: sum(eload(e) for e in owned[s]) for s in routable}
+            # replica service is real load on the serving shard but invisible
+            # to the registry-keyed entry rates (the entry lives on the
+            # primary): fold each shard's replica-served FIND EWMA in, or the
+            # model reads serving replicas as idle and churns moves (and
+            # `shed_replicas` teardowns) against phantom imbalance.
+            rep_rates = getattr(cl, "rep_rate_ewma", None) or {}
+            for s in routable:
+                loads[s] += self.rate_weight * rep_rates.get(s, 0.0)
+            total = sum(loads.values())
+            # the mean the policy steers toward is over the shards that will
+            # still hold data after the drains complete
+            mean = total / max(len(targets), 1)
 
-        # per-shard slot budget + per-entry claims of in-flight ops; both
-        # are maintained locally as commands are issued this pass. Snapshot
-        # ``cl.bgs`` once: on ShardMapBackend every access pulls the whole
-        # stacked table device-to-host
-        bgs = cl.bgs
-        free = {s: B.free_slots(bgs[s]) for s in routable}
-        claimed = {s: B.claimed_keys(bgs[s]) for s in routable}
+            # per-shard slot budget + per-entry claims of in-flight ops; both
+            # are maintained locally as commands are issued this pass. Snapshot
+            # ``cl.bgs`` once: on ShardMapBackend every access pulls the whole
+            # stacked table device-to-host
+            bgs = cl.bgs
+            free = {s: B.free_slots(bgs[s]) for s in routable}
+            claimed = {s: B.claimed_keys(bgs[s]) for s in routable}
 
-        # account load already *en route*: an in-flight Move's sublist
-        # still counts against its source until the registry transfer
-        # lands, so without this discount every pass during the (multi-
-        # round) copy re-diagnoses the same overload and dogpiles more
-        # moves onto it
-        for s in routable:
-            for key, tgt in B.active_moves(bgs[s]):
-                e = next((x for x in owned[s] if x["keymax"] == key), None)
-                if e is not None and tgt in loads and tgt != s:
-                    loads[s] -= eload(e)
-                    loads[tgt] += eload(e)
+            # account load already *en route*: an in-flight Move's sublist
+            # still counts against its source until the registry transfer
+            # lands, so without this discount every pass during the (multi-
+            # round) copy re-diagnoses the same overload and dogpiles more
+            # moves onto it
+            for s in routable:
+                for key, tgt in B.active_moves(bgs[s]):
+                    e = next((x for x in owned[s] if x["keymax"] == key), None)
+                    if e is not None and tgt in loads and tgt != s:
+                        loads[s] -= eload(e)
+                        loads[tgt] += eload(e)
 
-        # registry budget for *new* splits this pass. The registry is
-        # global (every split adds an entry on every replica), and a split
-        # whose stabilization finds it full waits in BG_SPLIT_WAIT
-        # forever — so the budget must discount (a) splits issued earlier
-        # in this pass, and (b) splits still in flight from previous
-        # passes on any shard, not just re-read a registry.size those
-        # entries haven't landed in yet.
-        inflight_splits = sum(
-            int(((ph == B.BG_SPLIT_EXEC) | (ph == B.BG_SPLIT_WAIT)).sum())
-            for ph in (B.slot_phases(bgs[s]) for s in routable))
-        reg_used = max(int(cl.states[s].registry.size) for s in range(cl.n))
-        reg_room = (cl.cfg.max_sublists - reg_used
-                    - self.registry_headroom - inflight_splits)
+            # registry budget for *new* splits this pass. The registry is
+            # global (every split adds an entry on every replica), and a split
+            # whose stabilization finds it full waits in BG_SPLIT_WAIT
+            # forever — so the budget must discount (a) splits issued earlier
+            # in this pass, and (b) splits still in flight from previous
+            # passes on any shard, not just re-read a registry.size those
+            # entries haven't landed in yet.
+            inflight_splits = sum(
+                int(((ph == B.BG_SPLIT_EXEC) | (ph == B.BG_SPLIT_WAIT)).sum())
+                for ph in (B.slot_phases(bgs[s]) for s in routable))
+            reg_used = max(int(cl.states[s].registry.size)
+                           for s in range(cl.n))
+            reg_room = (cl.cfg.max_sublists - reg_used
+                        - self.registry_headroom - inflight_splits)
 
-        def pick_target(exclude):
-            cands = [d for d in targets if d != exclude]
-            if not cands:
-                return None
-            if self.rng is not None:
-                # seeded tie-break among equally-loaded targets; min() is
-                # stable, so shuffling only reorders ties
-                cands = list(cands)
-                self.rng.shuffle(cands)
-            return min(cands, key=lambda d: loads[d])
-
-        # 0) evacuate draining shards: every sublist they own is force-
-        # moved onto the least-loaded target, bypassing the improvement
-        # gates of stage 2 — the point is to empty the shard, not to even
-        # the load (retire_shard's finish gate waits on owned == 0)
-        for s in draining:
-            for e in sorted(owned[s], key=lambda x: -x["size"]):
-                if free[s] <= 0:
-                    break
-                if e["keymax"] in claimed[s] or e["switched"]:
-                    continue
-                if shed_replicas(s, e["keymax"]):
-                    continue
-                tgt = pick_target(s)
-                if tgt is None:
-                    break
-                if cl.move(s, e["keymax"], tgt):
-                    issued["evacuate"] += 1
-                    free[s] -= 1
-                    claimed[s].add(e["keymax"])
-                    loads[s] -= eload(e)
-                    loads[tgt] += eload(e)
-
-        for s in targets:
-            entries = owned[s]
-
-            def unclaimed(e):
-                return e["keymax"] not in claimed[s] and not e["switched"]
-
-            # 1) split oversized sublists (registry budget permitting)
-            big = sorted((e for e in entries
-                          if e["size"] > self.split_threshold
-                          and unclaimed(e)),
-                         key=lambda x: -x["size"])
-            for e in big:
-                if free[s] <= 0 or reg_room <= 0:
-                    break
-                if shed_replicas(s, e["keymax"]):
-                    continue
-                mid = cl.middle_item(s, e["head_idx"])
-                if mid is None:
-                    continue
-                if cl.split(s, e["keymax"], mid):
-                    issued["split"] += 1
-                    free[s] -= 1
-                    reg_room -= 1
-                    claimed[s].add(e["keymax"])
-
-            # 2) move sublists off an overloaded shard; the working
-            # ``loads`` snapshot is adjusted per issued move so parallel
-            # donors (and repeated moves within this pass) spread over
-            # *currently* least-loaded targets instead of dogpiling the
-            # pass-start minimum
-            while (len(targets) > 1 and free[s] > 0
-                   and loads[s] > self.move_headroom * mean):
-                cands = [e for e in entries if unclaimed(e)]
+        with span("balancer.plan"):
+            def pick_target(exclude):
+                cands = [d for d in targets if d != exclude]
                 if not cands:
-                    break
-                tgt = pick_target(s)
-                if tgt is None or loads[s] - loads[tgt] <= 1:
-                    break
-                # move the sublist that best evens the load — but only
-                # if it strictly improves the pairwise imbalance (else a
-                # lone big sublist ping-pongs between shards forever)
-                gap = (loads[s] - loads[tgt]) / 2
-                e = min(cands, key=lambda x: abs(eload(x) - gap))
-                if loads[tgt] + eload(e) >= loads[s]:
-                    break
-                if shed_replicas(s, e["keymax"]):
-                    # replicas retire first; the move is re-evaluated on a
-                    # later pass once the entry is replica-free
-                    entries = [x for x in entries if x is not e]
-                    continue
-                if not cl.move(s, e["keymax"], tgt):
-                    break
-                issued["move"] += 1
-                free[s] -= 1
-                claimed[s].add(e["keymax"])
-                loads[s] -= eload(e)
-                loads[tgt] += eload(e)
-                entries = [x for x in entries if x is not e]
+                    return None
+                if self.rng is not None:
+                    # seeded tie-break among equally-loaded targets; min() is
+                    # stable, so shuffling only reorders ties
+                    cands = list(cands)
+                    self.rng.shuffle(cands)
+                return min(cands, key=lambda d: loads[d])
 
-            # 3) merge adjacent runts on the same shard
-            if self.merge_threshold > 0:
-                entries_sorted = sorted(entries, key=lambda x: x["keymin"])
-                for a, b in zip(entries_sorted, entries_sorted[1:]):
+            # 0) evacuate draining shards: every sublist they own is force-
+            # moved onto the least-loaded target, bypassing the improvement
+            # gates of stage 2 — the point is to empty the shard, not to even
+            # the load (retire_shard's finish gate waits on owned == 0)
+            for s in draining:
+                for e in sorted(owned[s], key=lambda x: -x["size"]):
                     if free[s] <= 0:
                         break
-                    if (a["keymax"] == b["keymin"]
-                            and a["size"] + b["size"] < self.merge_threshold
-                            and unclaimed(a) and unclaimed(b)):
-                        if (shed_replicas(s, a["keymax"])
-                                or shed_replicas(s, b["keymax"])):
-                            continue
-                        if cl.merge(s, a["keymax"], b["keymax"]):
-                            issued["merge"] += 1
-                            free[s] -= 1
-                            claimed[s].add(a["keymax"])
-                            claimed[s].add(b["keymax"])
-
-            # 4) hot-sublist read replication (§15): entries whose op-rate
-            # EWMA crossed the hot threshold get read replicas on the
-            # least-loaded other shards; entries that cooled below the
-            # (lower) cold threshold shed theirs. Claimed/switched entries
-            # are skipped — a sublist mid-restructure is about to change
-            # hands, and replicate-then-drop within one pass is churn.
-            if rep_on and len(targets) > 1:
-                total_rate = sum(rates.values())
-                for e in entries:
-                    kmax = e["keymax"]
-                    if not unclaimed(e):
+                    if e["keymax"] in claimed[s] or e["switched"]:
                         continue
-                    r = rates.get(kmax, 0.0)
-                    share = r / total_rate if total_rate > 0 else 0.0
-                    have = set(repsets.get(kmax, (0, 0, []))[2])
-                    if r >= self.hot_rate and share >= self.hot_share:
-                        cands = sorted((d for d in targets
-                                        if d != s and d not in have),
-                                       key=lambda d: loads[d])
-                        want = self.replica_fanout - len(have)
-                        for tgt in cands[:max(want, 0)]:
-                            if cl.replicate(s, kmax, tgt):
-                                issued["replicate"] += 1
-                                have.add(tgt)
-                            else:
-                                break   # session table full: stop asking
-                    elif have and r <= self.cold_rate:
-                        if cl.drop_replica(s, kmax):
-                            issued["drop"] += 1
-                        repsets.pop(kmax, None)
+                    if shed_replicas(s, e["keymax"]):
+                        continue
+                    tgt = pick_target(s)
+                    if tgt is None:
+                        break
+                    if cl.move(s, e["keymax"], tgt):
+                        issued["evacuate"] += 1
+                        free[s] -= 1
+                        claimed[s].add(e["keymax"])
+                        loads[s] -= eload(e)
+                        loads[tgt] += eload(e)
+
+            for s in targets:
+                entries = owned[s]
+
+                def unclaimed(e):
+                    return e["keymax"] not in claimed[s] and not e["switched"]
+
+                # 1) split oversized sublists (registry budget permitting)
+                big = sorted((e for e in entries
+                              if e["size"] > self.split_threshold
+                              and unclaimed(e)),
+                             key=lambda x: -x["size"])
+                for e in big:
+                    if free[s] <= 0 or reg_room <= 0:
+                        break
+                    if shed_replicas(s, e["keymax"]):
+                        continue
+                    mid = cl.middle_item(s, e["head_idx"])
+                    if mid is None:
+                        continue
+                    if cl.split(s, e["keymax"], mid):
+                        issued["split"] += 1
+                        free[s] -= 1
+                        reg_room -= 1
+                        claimed[s].add(e["keymax"])
+
+                # 2) move sublists off an overloaded shard; the working
+                # ``loads`` snapshot is adjusted per issued move so parallel
+                # donors (and repeated moves within this pass) spread over
+                # *currently* least-loaded targets instead of dogpiling the
+                # pass-start minimum
+                while (len(targets) > 1 and free[s] > 0
+                       and loads[s] > self.move_headroom * mean):
+                    cands = [e for e in entries if unclaimed(e)]
+                    if not cands:
+                        break
+                    tgt = pick_target(s)
+                    if tgt is None or loads[s] - loads[tgt] <= 1:
+                        break
+                    # move the sublist that best evens the load — but only
+                    # if it strictly improves the pairwise imbalance (else a
+                    # lone big sublist ping-pongs between shards forever)
+                    gap = (loads[s] - loads[tgt]) / 2
+                    e = min(cands, key=lambda x: abs(eload(x) - gap))
+                    if loads[tgt] + eload(e) >= loads[s]:
+                        break
+                    if shed_replicas(s, e["keymax"]):
+                        # replicas retire first; the move is re-evaluated on a
+                        # later pass once the entry is replica-free
+                        entries = [x for x in entries if x is not e]
+                        continue
+                    if not cl.move(s, e["keymax"], tgt):
+                        break
+                    issued["move"] += 1
+                    free[s] -= 1
+                    claimed[s].add(e["keymax"])
+                    loads[s] -= eload(e)
+                    loads[tgt] += eload(e)
+                    entries = [x for x in entries if x is not e]
+
+                # 3) merge adjacent runts on the same shard
+                if self.merge_threshold > 0:
+                    entries_sorted = sorted(entries, key=lambda x: x["keymin"])
+                    for a, b in zip(entries_sorted, entries_sorted[1:]):
+                        if free[s] <= 0:
+                            break
+                        if (a["keymax"] == b["keymin"]
+                                and (a["size"] + b["size"]
+                                     < self.merge_threshold)
+                                and unclaimed(a) and unclaimed(b)):
+                            if (shed_replicas(s, a["keymax"])
+                                    or shed_replicas(s, b["keymax"])):
+                                continue
+                            if cl.merge(s, a["keymax"], b["keymax"]):
+                                issued["merge"] += 1
+                                free[s] -= 1
+                                claimed[s].add(a["keymax"])
+                                claimed[s].add(b["keymax"])
+
+                # 4) hot-sublist read replication (§15): entries whose op-rate
+                # EWMA crossed the hot threshold get read replicas on the
+                # least-loaded other shards; entries that cooled below the
+                # (lower) cold threshold shed theirs. Claimed/switched entries
+                # are skipped — a sublist mid-restructure is about to change
+                # hands, and replicate-then-drop within one pass is churn.
+                if rep_on and len(targets) > 1:
+                    total_rate = sum(rates.values())
+                    for e in entries:
+                        kmax = e["keymax"]
+                        if not unclaimed(e):
+                            continue
+                        r = rates.get(kmax, 0.0)
+                        share = r / total_rate if total_rate > 0 else 0.0
+                        have = set(repsets.get(kmax, (0, 0, []))[2])
+                        if r >= self.hot_rate and share >= self.hot_share:
+                            cands = sorted((d for d in targets
+                                            if d != s and d not in have),
+                                           key=lambda d: loads[d])
+                            want = self.replica_fanout - len(have)
+                            for tgt in cands[:max(want, 0)]:
+                                if cl.replicate(s, kmax, tgt):
+                                    issued["replicate"] += 1
+                                    have.add(tgt)
+                                else:
+                                    break   # session table full: stop asking
+                        elif have and r <= self.cold_rate:
+                            if cl.drop_replica(s, kmax):
+                                issued["drop"] += 1
+                            repsets.pop(kmax, None)
         return issued
 
 

@@ -21,10 +21,15 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import bg as B
 from . import messages as M
-from .shard import shard_round
+from .shard import COUNTERS, shard_round
 from .types import DiLiConfig, ShardState
 
 AXIS = "shard"
+# make_dili_round's stats row: the shard-round's COUNTERS, then these
+# three counts over the rows the all_to_all routed to the shard
+ROUTED_LIVE = len(COUNTERS)         # live rows (quiescence signal)
+ROUTED_OPS = len(COUNTERS) + 1      # delegated MSG_OP rows
+ROUTED_HOPS = len(COUNTERS) + 2     # max delegation-hop count among them
 
 
 def bucket_by_dst(outbox, count, num_shards: int, cap_pair: int):
@@ -62,22 +67,12 @@ def make_dili_round(mesh: Mesh, cfg: DiLiConfig, cap_pair: int = 8):
     API); ``comp_key`` tags completion rows — SH_KEY for scalar results,
     a real key for RANGE items (DESIGN.md §16; the routed inbox never
     crosses to the host on this path, so the completion lanes are the
-    only channel scan items can ride). ``stats`` is int32[9] per shard,
+    only channel scan items can ride). ``stats`` is int32 per shard,
     computed on-device so the host driver never pulls the routed inbox:
-
-      0  out_count — attempted outbox pushes (detects ``bucket_by_dst``
-         overflow instead of silently losing rows)
-      1  live rows routed to this shard (quiescence signal)
-      2  delegated MSG_OP rows routed to this shard
-      3  max delegation-hop count among those rows
-      4  background slots still busy after the round (quiescence +
-         rebalance-concurrency signal)
-      5  MoveItems replayed by the batched scatter splice this round
-      6  fast-path lanes answered via the packed-block kernel probe
-         (DESIGN.md §12)
-      7  FINDs answered from a replica slot (DESIGN.md §15)
-      8  RANGE segments served by the packed-block gather pre-pass
-         (DESIGN.md §16)
+    the shard-round's ``COUNTERS`` (``out_count`` first, which detects
+    ``bucket_by_dst`` overflow instead of silently losing rows), then at
+    ``ROUTED_LIVE``/``ROUTED_OPS``/``ROUTED_HOPS`` the live rows, the
+    delegated MSG_OP rows and their max hop count routed to the shard.
 
     The trailing ``ent_hits`` output is int32[S, M]: per-entry op
     attribution this round (the balancer's op-rate EWMA feed).
@@ -94,25 +89,22 @@ def make_dili_round(mesh: Mesh, cfg: DiLiConfig, cap_pair: int = 8):
         client = client[0]
         me = jax.lax.axis_index(axes)
         out = shard_round(state, bg, me, inbox, client, cfg)
-        buckets, _ = bucket_by_dst(out.outbox, out.out_count, num, cap_pair)
-        # route: one all_to_all over the flattened mesh axes (paper's RPCs)
-        routed = jax.lax.all_to_all(buckets, axes, split_axis=0,
-                                    concat_axis=0)
+        with jax.named_scope("round.exchange"):
+            buckets, _ = bucket_by_dst(out.outbox, out.out_count, num,
+                                       cap_pair)
+            # route: one all_to_all over the flattened mesh axes (the
+            # paper's RPCs)
+            routed = jax.lax.all_to_all(buckets, axes, split_axis=0,
+                                        concat_axis=0)
         inbox_next = routed.reshape(1, num * cap_pair, M.FIELDS)
         rows = inbox_next[0]
         live = rows[:, M.F_KIND] != M.MSG_NONE
         is_op = rows[:, M.F_KIND] == M.MSG_OP
-        stats = jnp.stack([
-            out.out_count,
+        stats = jnp.concatenate([out.counters, jnp.stack([
             jnp.sum(live).astype(jnp.int32),
             jnp.sum(is_op).astype(jnp.int32),
             jnp.max(jnp.where(is_op, rows[:, M.F_X2], 0)).astype(jnp.int32),
-            out.bg_active,
-            out.move_hits,
-            out.blk_hits,
-            out.rep_hits,
-            out.range_hits,
-        ])
+        ])])
         add1 = lambda x: x[None]
         return (jax.tree_util.tree_map(add1, out.state),
                 jax.tree_util.tree_map(add1, out.bg),
@@ -143,8 +135,7 @@ def make_dili_round_hostroute(mesh: Mesh, cfg: DiLiConfig):
          stats)
 
     ``outbox`` is the raw [S, mailbox_cap, FIELDS] per-shard outbox;
-    ``stats`` is int32[8] per shard: out_count, bg_active, move_hits,
-    fast_hits, mut_hits, blk_hits, rep_hits, range_hits; the trailing
+    ``stats`` is the shard-round's ``COUNTERS`` per shard; the trailing
     ``ent_hits`` output is int32[S, M] per-entry op attribution.
     Delegation stats (hops) are computed host-side from the outbox rows
     themselves — the host sees every frame on this path.
@@ -158,22 +149,12 @@ def make_dili_round_hostroute(mesh: Mesh, cfg: DiLiConfig):
         bg = jax.tree_util.tree_map(lambda x: x[0], bg)
         me = jax.lax.axis_index(axes)
         out = shard_round(state, bg, me, inbox[0], client[0], cfg)
-        stats = jnp.stack([
-            out.out_count,
-            out.bg_active,
-            out.move_hits,
-            out.fast_hits,
-            out.mut_hits,
-            out.blk_hits,
-            out.rep_hits,
-            out.range_hits,
-        ])
         add1 = lambda x: x[None]
         return (jax.tree_util.tree_map(add1, out.state),
                 jax.tree_util.tree_map(add1, out.bg),
                 out.outbox[None],
                 out.comp_slot[None], out.comp_val[None],
-                out.comp_src[None], out.comp_key[None], stats[None],
+                out.comp_src[None], out.comp_key[None], out.counters[None],
                 out.ent_hits[None])
 
     pspec = P(axes)

@@ -56,25 +56,44 @@ class RoundOut(NamedTuple):
                              # (comp_val is then the item's value and the
                              # host accumulates it instead of publishing
                              # a result; DESIGN.md §16)
-    fast_hits: jnp.ndarray   # int32 — finds answered by the fast-path
-    mut_hits: jnp.ndarray    # int32 — mutations applied by the fast-path
-    bg_active: jnp.ndarray   # int32 — background slots busy after the round
-    move_hits: jnp.ndarray   # int32 — MoveItems replayed by the batched
-                             # scatter splice (vs the serial walk)
-    blk_hits: jnp.ndarray    # int32 — fast-path lanes whose stage-2 probe
-                             # was the packed-block hybrid-search kernel
-                             # (subset of fast_hits + mut_hits;
-                             # DESIGN.md §12)
-    rep_hits: jnp.ndarray    # int32 — FINDs answered from a replica slot
-                             # (DESIGN.md §15)
-    range_hits: jnp.ndarray  # int32 — RANGE segments served by the
-                             # packed-block gather pre-pass (vs the
-                             # serial chain walk; DESIGN.md §16)
+    counters: jnp.ndarray    # int32[len(COUNTERS)] — the round's scalar
+                             # counters, packed so the host pulls them
+                             # in one transfer
     ent_hits: jnp.ndarray    # int32[M] — ops this round attributed to
                              # each local registry entry (owned-entry
                              # arrivals + replica serves). The host feeds
                              # these into the per-entry op-rate EWMA the
                              # balancer's load model reads.
+
+
+# The scalar counters of one shard-round, in ``RoundOut.counters`` order.
+COUNTERS = (
+    "out_count",    # outbox pushes attempted (> mailbox_cap: rows lost)
+    "fast_hits",    # finds answered by the fast-path
+    "mut_hits",     # mutations applied by the fast-path
+    "move_hits",    # MoveItems replayed by the batched scatter splice
+                    # (vs the serial walk)
+    "blk_hits",     # fast-path lanes whose stage-2 probe was the
+                    # packed-block hybrid-search kernel (subset of
+                    # fast_hits + mut_hits; DESIGN.md §12)
+    "rep_hits",     # FINDs answered from a replica slot (DESIGN.md §15)
+    "range_hits",   # RANGE segments served by the packed-block gather
+                    # pre-pass (vs the serial chain walk; DESIGN.md §16)
+    "bg_active",    # background slots busy after the round
+    "serial_rows",  # rows the serial loop executed
+)
+CTR = {name: i for i, name in enumerate(COUNTERS)}
+_SUMMED = ("fast_hits", "mut_hits", "move_hits", "blk_hits", "rep_hits",
+           "range_hits", "serial_rows")
+
+
+def add_counters(stats, ctr) -> None:
+    """Fold shard-round counters (int[S, >= len(COUNTERS)], a row per
+    shard, ``COUNTERS`` first) into a backend's ``stats``."""
+    for name in _SUMMED:
+        stats[name] += int(ctr[:, CTR[name]].sum())
+    stats["max_bg_active"] = max(stats["max_bg_active"],
+                                 int(ctr[:, CTR["bg_active"]].max()))
 
 
 # handlers return (state, bg, outbox, count, cslot, cval, csrc, ckey);
@@ -175,28 +194,31 @@ def shard_round(state: ShardState, bg: B.BgTable, me, inbox, client,
     # the mirror: replica_step publishes blk rows as session images
     # (§15), so a replicating shard refreshes even with the probe off.
     # With both off, the mirror stays all-invalid and costs nothing.
-    if cfg.block_probe or cfg.replication or cfg.range_scan:
-        state = BL.refresh_blocks(state, me, cfg)
+    with jax.named_scope("round.blocks"):
+        if cfg.block_probe or cfg.replication or cfg.range_scan:
+            state = BL.refresh_blocks(state, me, cfg)
 
     # RANGE gather pre-pass (DESIGN.md §16): serve scan cursors whose
     # covering entry has a valid packed block, against the same
     # round-start snapshot the blocks mirror — before anything mutates.
     # Unserved cursors fall through to the serial h_range walk.
-    if cfg.range_scan:
-        outbox, count, range_handled, range_hits = RS.range_prepass(
-            state, rows, me, outbox, count, cfg)
-    else:
-        range_handled = jnp.zeros((n_rows,), bool)
-        range_hits = jnp.zeros((), jnp.int32)
+    with jax.named_scope("round.range"):
+        if cfg.range_scan:
+            outbox, count, range_handled, range_hits = RS.range_prepass(
+                state, rows, me, outbox, count, cfg)
+        else:
+            range_handled = jnp.zeros((n_rows,), bool)
+            range_hits = jnp.zeros((), jnp.int32)
 
     # one combined pre-pass: answers eligible FINDs from round-start state
     # and applies eligible INSERT/REMOVEs against it (eligible finds never
     # share a key with a mutation, so their relative order is immaterial),
     # sharing a single route-resolve + bounded gather-walk.
-    pre = BA.round_prepass(state, rows, me, cfg,
-                           run_find=cfg.find_fastpath,
-                           run_mut=cfg.mut_fastpath)
-    state = pre.state
+    with jax.named_scope("round.prepass"):
+        pre = BA.round_prepass(state, rows, me, cfg,
+                               run_find=cfg.find_fastpath,
+                               run_mut=cfg.mut_fastpath)
+        state = pre.state
 
     # migration rounds get their own pre-pass (mutually exclusive with the
     # client one — any move row makes the round non-benign for §4/§4b):
@@ -205,138 +227,151 @@ def shard_round(state: ShardState, bg: B.BgTable, me, inbox, client,
     # messages. Acks interact with the source only through per-slot
     # counters and newLoc writes, so their position among the round's
     # other outbox rows is not semantically ordered (DESIGN.md §10).
-    mrp = B.replay_prepass(state, rows, me, outbox, count, cfg)
-    state, outbox, count = mrp.state, mrp.outbox, mrp.count
+    with jax.named_scope("round.replay"):
+        mrp = B.replay_prepass(state, rows, me, outbox, count, cfg)
+        state, outbox, count = mrp.state, mrp.outbox, mrp.count
 
     # replica read pre-pass (DESIGN.md §15): fresh local FINDs whose key
     # lands in a serving replica slot are answered from the packed image
     # and skip the serial loop. Compiled out unless cfg.replication.
-    if cfg.replication:
-        rep_elig, rep_res = R.replica_serve(state, rows, me, cfg)
-        rep_elig = rep_elig & ~pre.find_elig & ~pre.mut_elig & ~mrp.handled
-    else:
-        rep_elig = jnp.zeros((n_rows,), bool)
-        rep_res = jnp.zeros((n_rows,), jnp.int32)
+    with jax.named_scope("round.replica"):
+        if cfg.replication:
+            rep_elig, rep_res = R.replica_serve(state, rows, me, cfg)
+            rep_elig = (rep_elig & ~pre.find_elig & ~pre.mut_elig
+                        & ~mrp.handled)
+        else:
+            rep_elig = jnp.zeros((n_rows,), bool)
+            rep_res = jnp.zeros((n_rows,), jnp.int32)
 
-    # Stable-partition the rows the serial pass must execute to the front,
-    # so it runs a *dynamic* trip count: padding costs nothing (rounds are
-    # usually mostly MSG_NONE), and fast-path-answered rows never enter
-    # the loop at all — fast finds neither mutate state nor emit messages,
-    # and fast mutations commute with every remaining row and emit nothing
-    # either, so removing them leaves the remaining rows' serial order (and
-    # with it per-(src,dst) FIFO) intact. The composite key skip*n + i is
-    # unique, so the sort is order-preserving on the kept rows.
-    skip = (rows[:, M.F_KIND] == M.MSG_NONE) | pre.find_elig \
-        | pre.mut_elig | mrp.handled | rep_elig | range_handled
-    # blanket packed-block invalidation trigger (DESIGN.md §12): any row
-    # the serial loop will execute, other than pure result routing and
-    # transport acks, may mutate a chain or shift the registry's entry
-    # indexing — per-entry attribution is done where the writer knows the
-    # entry (fast-path apply, bg phase hooks); everything else drops the
-    # whole mirror below.
-    kind0 = rows[:, M.F_KIND]
-    # replica rows rewrite only the rslots tables — never a chain, never
-    # the registry — so they don't trigger the blanket block drop.
-    # RANGE rows are pure reads (serial h_range walks without delinking),
-    # so they don't either.
-    serial_mut = jnp.any((~skip) & (kind0 != M.MSG_NONE)
-                         & (kind0 != M.MSG_RESULT)
-                         & (kind0 != M.MSG_NET_ACK)
-                         & (kind0 != M.MSG_EPOCH)
-                         & (kind0 != M.MSG_REPLICA_DELTA)
-                         & (kind0 != M.MSG_REPLICA_INSTALL)
-                         & (kind0 != M.MSG_REPLICA_DROP)
-                         & (kind0 != M.MSG_RANGE)
-                         & (kind0 != M.MSG_RANGE_ITEM))
+    with jax.named_scope("round.order"):
+        # Stable-partition the rows the serial pass must execute to the front,
+        # so it runs a *dynamic* trip count: padding costs nothing (rounds are
+        # usually mostly MSG_NONE), and fast-path-answered rows never enter
+        # the loop at all — fast finds neither mutate state nor emit messages,
+        # and fast mutations commute with every remaining row and emit nothing
+        # either, so removing them leaves the remaining rows' serial order (and
+        # with it per-(src,dst) FIFO) intact. The composite key skip*n + i is
+        # unique, so the sort is order-preserving on the kept rows.
+        skip = (rows[:, M.F_KIND] == M.MSG_NONE) | pre.find_elig \
+            | pre.mut_elig | mrp.handled | rep_elig | range_handled
+        # blanket packed-block invalidation trigger (DESIGN.md §12): any row
+        # the serial loop will execute, other than pure result routing and
+        # transport acks, may mutate a chain or shift the registry's entry
+        # indexing — per-entry attribution is done where the writer knows the
+        # entry (fast-path apply, bg phase hooks); everything else drops the
+        # whole mirror below.
+        kind0 = rows[:, M.F_KIND]
+        # replica rows rewrite only the rslots tables — never a chain, never
+        # the registry — so they don't trigger the blanket block drop.
+        # RANGE rows are pure reads (serial h_range walks without delinking),
+        # so they don't either.
+        serial_mut = jnp.any((~skip) & (kind0 != M.MSG_NONE)
+                             & (kind0 != M.MSG_RESULT)
+                             & (kind0 != M.MSG_NET_ACK)
+                             & (kind0 != M.MSG_EPOCH)
+                             & (kind0 != M.MSG_REPLICA_DELTA)
+                             & (kind0 != M.MSG_REPLICA_INSTALL)
+                             & (kind0 != M.MSG_REPLICA_DROP)
+                             & (kind0 != M.MSG_RANGE)
+                             & (kind0 != M.MSG_RANGE_ITEM))
 
-    # per-entry op attribution (pre-reorder): an MSG_OP row counts at the
-    # shard that will answer it — owned-entry arrivals here, or a replica
-    # serve here; delegated-away rows count on arrival at their owner.
-    m_ent = state.registry.keymin.shape[0]
-    ent = REG.get_by_key(state.registry, rows[:, M.F_KEY])
-    entc = jnp.clip(ent, 0, m_ent - 1)
-    owned_ent = (ent >= 0) & \
-        (refs.ref_sid(state.registry.subhead[entc]) == me)
-    count_here = (kind0 == M.MSG_OP) & (owned_ent | rep_elig)
-    ent_hits = jnp.zeros((m_ent,), jnp.int32).at[
-        jnp.where(count_here, entc, m_ent)].add(1, mode="drop")
+        # per-entry op attribution (pre-reorder): an MSG_OP row counts at the
+        # shard that will answer it — owned-entry arrivals here, or a replica
+        # serve here; delegated-away rows count on arrival at their owner.
+        m_ent = state.registry.keymin.shape[0]
+        ent = REG.get_by_key(state.registry, rows[:, M.F_KEY])
+        entc = jnp.clip(ent, 0, m_ent - 1)
+        owned_ent = (ent >= 0) & \
+            (refs.ref_sid(state.registry.subhead[entc]) == me)
+        count_here = (kind0 == M.MSG_OP) & (owned_ent | rep_elig)
+        ent_hits = jnp.zeros((m_ent,), jnp.int32).at[
+            jnp.where(count_here, entc, m_ent)].add(1, mode="drop")
 
-    order = jnp.argsort(skip.astype(jnp.int32) * n_rows
-                        + jnp.arange(n_rows, dtype=jnp.int32))
-    rows = rows[order]
-    elig = pre.find_elig[order]
-    melig = pre.mut_elig[order]
-    relig = rep_elig[order]
-    res_all = jnp.where(rep_elig, rep_res, pre.res)
-    n_live = jnp.sum(~skip)
+        order = jnp.argsort(skip.astype(jnp.int32) * n_rows
+                            + jnp.arange(n_rows, dtype=jnp.int32))
+        rows = rows[order]
+        elig = pre.find_elig[order]
+        melig = pre.mut_elig[order]
+        relig = rep_elig[order]
+        res_all = jnp.where(rep_elig, rep_res, pre.res)
+        n_live = jnp.sum(~skip)
 
-    branches = []
-    for kind in range(_N_KINDS):
-        fn = _HANDLERS.get(kind, _noop)
+        branches = []
+        for kind in range(_N_KINDS):
+            fn = _HANDLERS.get(kind, _noop)
 
-        def mk(f):
-            def br(args):
-                st, b, row, ob, ct = args
-                return f(st, b, me, row, ob, ct, cfg)
-            return br
+            def mk(f):
+                def br(args):
+                    st, b, row, ob, ct = args
+                    return f(st, b, me, row, ob, ct, cfg)
+                return br
 
-        branches.append(mk(fn))
+            branches.append(mk(fn))
 
-    def cond(c):
-        return c[0] < n_live
+        def cond(c):
+            return c[0] < n_live
 
-    def body(c):
-        i, st, b, ob, ct, cslots, cvals, csrcs, ckeys = c
-        row = rows[i]
-        kind = jnp.clip(row[M.F_KIND], 0, _N_KINDS - 1)
-        st, b, ob, ct, cs, cv, cr, ck = jax.lax.switch(
-            kind, branches, (st, b, row, ob, ct))
-        return (i + 1, st, b, ob, ct,
-                cslots.at[i].set(cs), cvals.at[i].set(cv),
-                csrcs.at[i].set(cr), ckeys.at[i].set(ck))
+        def body(c):
+            i, st, b, ob, ct, cslots, cvals, csrcs, ckeys = c
+            row = rows[i]
+            kind = jnp.clip(row[M.F_KIND], 0, _N_KINDS - 1)
+            st, b, ob, ct, cs, cv, cr, ck = jax.lax.switch(
+                kind, branches, (st, b, row, ob, ct))
+            return (i + 1, st, b, ob, ct,
+                    cslots.at[i].set(cs), cvals.at[i].set(cv),
+                    csrcs.at[i].set(cr), ckeys.at[i].set(ck))
 
-    # completions start pre-filled with the pre-pass answers (those rows
-    # sit past n_live); the serial loop overwrites its own rows' slots.
-    # Pre-pass rows are local clients answered here, so their src is ``me``.
-    init = (jnp.zeros((), jnp.int32), state, bg, outbox, count,
-            jnp.where(elig | melig | relig,
-                      rows[:, M.F_TS], -1).astype(jnp.int32),
-            jnp.where(elig | melig | relig,
-                      res_all[order], 0).astype(jnp.int32),
-            jnp.full((n_rows,), me, jnp.int32),
-            jnp.full((n_rows,), SH_KEY, jnp.int32))
-    (_, state, bg, outbox, count, cslots, cvals, csrcs,
-     ckeys) = jax.lax.while_loop(cond, body, init)
+        # completions start pre-filled with the pre-pass answers (those rows
+        # sit past n_live); the serial loop overwrites its own rows' slots.
+        # Pre-pass rows are local clients answered here, so their src is ``me``.
+        init = (jnp.zeros((), jnp.int32), state, bg, outbox, count,
+                jnp.where(elig | melig | relig,
+                          rows[:, M.F_TS], -1).astype(jnp.int32),
+                jnp.where(elig | melig | relig,
+                          res_all[order], 0).astype(jnp.int32),
+                jnp.full((n_rows,), me, jnp.int32),
+                jnp.full((n_rows,), SH_KEY, jnp.int32))
+    with jax.named_scope("round.serial"):
+        (_, state, bg, outbox, count, cslots, cvals, csrcs,
+         ckeys) = jax.lax.while_loop(cond, body, init)
 
-    bg_busy = jnp.any(bg.phase != B.BG_IDLE)
-    state, bg, outbox, count = B.bg_step(state, bg, me, outbox, count, cfg)
-    bg_busy = bg_busy | jnp.any(bg.phase != B.BG_IDLE)
+    with jax.named_scope("round.bg"):
+        bg_busy = jnp.any(bg.phase != B.BG_IDLE)
+        state, bg, outbox, count = B.bg_step(state, bg, me, outbox, count,
+                                             cfg)
+        bg_busy = bg_busy | jnp.any(bg.phase != B.BG_IDLE)
 
     # publication engine (DESIGN.md §15): runs after the serial loop and
     # bg step so a fresh image walk already sees this round's mutations —
     # a change at the primary is on the wire the same round it happened.
-    if cfg.replication:
-        traffic = jnp.any(kind0 != M.MSG_NONE)
-        mutated = serial_mut | jnp.any(pre.mut_elig) | bg_busy
-        state, outbox, count = R.replica_step(
-            state, me, mutated, traffic, outbox, count, cfg)
+    with jax.named_scope("round.replica"):
+        if cfg.replication:
+            traffic = jnp.any(kind0 != M.MSG_NONE)
+            mutated = serial_mut | jnp.any(pre.mut_elig) | bg_busy
+            state, outbox, count = R.replica_step(
+                state, me, mutated, traffic, outbox, count, cfg)
 
-    # blanket invalidation: serial mutating rows, any bg slot active
-    # around bg_step, or a replayed move splice — a stale valid bit here
-    # would let next round's block probe answer from a chain that changed.
-    dirty_all = serial_mut | bg_busy | jnp.any(mrp.handled)
-    state = state._replace(blk=state.blk._replace(
-        valid=jnp.where(dirty_all, jnp.zeros_like(state.blk.valid),
-                        state.blk.valid)))
-    return RoundOut(state=state, bg=bg, outbox=outbox, out_count=count,
-                    comp_slot=cslots, comp_val=cvals, comp_src=csrcs,
-                    comp_key=ckeys,
-                    fast_hits=jnp.sum(pre.find_elig).astype(jnp.int32),
-                    mut_hits=jnp.sum(pre.mut_elig).astype(jnp.int32),
-                    bg_active=jnp.sum(bg.phase != B.BG_IDLE)
-                    .astype(jnp.int32),
-                    move_hits=jnp.sum(mrp.handled).astype(jnp.int32),
-                    blk_hits=pre.blk_hits,
-                    rep_hits=jnp.sum(rep_elig).astype(jnp.int32),
-                    range_hits=range_hits,
-                    ent_hits=ent_hits)
+    with jax.named_scope("round.finish"):
+        # blanket invalidation: serial mutating rows, any bg slot active
+        # around bg_step, or a replayed move splice — a stale valid bit here
+        # would let next round's block probe answer from a chain that changed.
+        dirty_all = serial_mut | bg_busy | jnp.any(mrp.handled)
+        state = state._replace(blk=state.blk._replace(
+            valid=jnp.where(dirty_all, jnp.zeros_like(state.blk.valid),
+                            state.blk.valid)))
+        counters = dict(
+            out_count=count,
+            fast_hits=jnp.sum(pre.find_elig),
+            mut_hits=jnp.sum(pre.mut_elig),
+            move_hits=jnp.sum(mrp.handled),
+            blk_hits=pre.blk_hits,
+            rep_hits=jnp.sum(rep_elig),
+            range_hits=range_hits,
+            bg_active=jnp.sum(bg.phase != B.BG_IDLE),
+            serial_rows=n_live)
+        return RoundOut(state=state, bg=bg, outbox=outbox, out_count=count,
+                        comp_slot=cslots, comp_val=cvals, comp_src=csrcs,
+                        comp_key=ckeys,
+                        counters=jnp.stack([counters[n] for n in COUNTERS])
+                        .astype(jnp.int32),
+                        ent_hits=ent_hits)

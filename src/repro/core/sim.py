@@ -39,7 +39,8 @@ from .durability import Durability, wal
 from .membership import (Membership, epoch_broadcast, moves_targeting,
                          owned_entry_count)
 from .net import Nemesis, NemesisConfig, Transport, trace_entry
-from .shard import shard_round
+from .shard import CTR, add_counters, shard_round
+from .spans import span
 from .types import (DiLiConfig, KEY_MAX, KEY_MIN, OP_FIND, OP_INSERT,
                     OP_REMOVE, SH_KEY, ST_KEY, ShardState, init_shard)
 
@@ -355,7 +356,7 @@ class Cluster:
         self.stats = {"max_outbox": 0, "max_hops": 0, "rounds": 0,
                       "fast_hits": 0, "mut_hits": 0, "delegated": 0,
                       "move_hits": 0, "blk_hits": 0, "max_bg_active": 0,
-                      "rep_hits": 0, "range_hits": 0}
+                      "rep_hits": 0, "range_hits": 0, "serial_rows": 0}
         # per-entry op-rate EWMA (keyed by entry keymax), fed from every
         # round's RoundOut.ent_hits — the load signal the balancer's
         # op-rate model and hot-entry replication stage read (§15). Decays
@@ -597,197 +598,196 @@ class Cluster:
     def step(self) -> int:
         """One synchronized round across all shards. Returns #completed."""
         cfg = self.cfg
-        self._apply_crash_plans()
-        down = self._down()
-        outs = []
-        client_feeds: List[np.ndarray] = []
-        for s in range(self.n):
-            if s in down:
-                outs.append(None)
-                client_feeds.append(np.zeros((0, M.FIELDS), np.int32))
-                continue
-            # feed: backlog first (FIFO), bounded by in_cap
-            feed = self.backlog[s][:self.in_cap]
-            self.backlog[s] = self.backlog[s][self.in_cap:]
-            inbox = np.zeros((self.in_cap, M.FIELDS), np.int32)
-            inbox[:feed.shape[0]] = feed
-            client = np.zeros((0, M.FIELDS), np.int32)
-            client_feeds.append(client)
-            out = shard_round(self.states[s], self.bgs[s], s,
-                              jnp.asarray(inbox),
-                              jnp.asarray(client.reshape(0, M.FIELDS)),
-                              cfg)
-            outs.append(out)
-
-        ndone = 0
-        self.last_completions = []
-        new_msgs: List[np.ndarray] = []
-        out_counts: List[int] = []
-        comp_by_shard: List[np.ndarray] = []
-        ent_rates: Dict[int, int] = {}
-        rep_served: Dict[int, int] = {}
-        for s, out in enumerate(outs):
-            if out is None:                      # crashed: emitted nothing
-                out_counts.append(0)
-                comp_by_shard.append(np.zeros((0, 4), np.int32))
-                continue
-            self.states[s] = out.state
-            self.bgs[s] = out.bg
-            self.stats["fast_hits"] += int(out.fast_hits)
-            self.stats["mut_hits"] += int(out.mut_hits)
-            self.stats["move_hits"] += int(out.move_hits)
-            self.stats["blk_hits"] += int(out.blk_hits)
-            rh = int(out.rep_hits)
-            self.stats["rep_hits"] += rh
-            self.stats["range_hits"] += int(out.range_hits)
-            if rh:
-                rep_served[s] = rep_served.get(s, 0) + rh
-            self.stats["max_bg_active"] = max(self.stats["max_bg_active"],
-                                              int(out.bg_active))
-            hits = np.asarray(out.ent_hits)
-            nz = np.nonzero(hits)[0]
-            if nz.size:
-                kmax = np.asarray(out.state.registry.keymax)
-                for e in nz:
-                    k = int(kmax[e])
-                    if k != ST_KEY:
-                        ent_rates[k] = ent_rates.get(k, 0) + int(hits[e])
-            cnt = int(out.out_count)
-            out_counts.append(cnt)
-            self.stats["max_outbox"] = max(self.stats["max_outbox"], cnt)
-            if cnt > cfg.mailbox_cap:
-                # not an assert: under ``python -O`` a dropped message
-                # (replicate/ack) would silently deadlock run_until_quiet.
-                raise OutboxOverflow(
-                    f"shard {s} emitted {cnt} messages in round "
-                    f"{self.round_no}, mailbox_cap={cfg.mailbox_cap}: "
-                    f"{cnt - cfg.mailbox_cap} rows dropped — raise "
-                    f"mailbox_cap or reduce the per-round feed")
-            ob = np.asarray(out.outbox)[:cnt]
-            if ob.size:
-                new_msgs.append((s, ob))
-                hops = ob[ob[:, M.F_KIND] == M.MSG_OP, M.F_X2]
-                if hops.size:
-                    self.stats["max_hops"] = max(self.stats["max_hops"],
-                                                 int(hops.max()))
-                    self.stats["delegated"] += int(hops.size)
-            cs = np.asarray(out.comp_slot)
-            cv = np.asarray(out.comp_val)
-            cr = np.asarray(out.comp_src)
-            ck = np.asarray(out.comp_key)
-            done = cs >= 0
-            comp_by_shard.append(np.stack(
-                [cs[done], cv[done], cr[done], ck[done]],
-                axis=1).astype(np.int32))
-            for slot, val, src, key in zip(cs[done], cv[done], cr[done],
-                                           ck[done]):
-                slot = int(slot)
-                if int(key) != SH_KEY:
-                    # one RANGE item — accumulate, publication waits for
-                    # the terminal count (DESIGN.md §16)
-                    self._range_parts.setdefault(slot, []).append(
-                        (int(key), int(val)))
-                    continue
-                if slot in self._range_ops:
-                    # terminal scan result: F_A is the total item count
-                    self._range_done[slot] = (int(val), int(src))
-                    continue
-                self.results[slot] = int(val)
-                self.result_src[slot] = int(src)
-                self.last_completions.append((slot, int(val), int(src)))
-                self._pending_ops.pop(slot, None)
-                ndone += 1
-        ndone += self._publish_ranges()
-
-        # per-entry op-rate EWMA update (once per round): decay every
-        # tracked entry, add this round's hits, drop entries decayed to
-        # noise so the dict tracks only recently-active sublists.
-        alpha = 0.3
-        nxt_rates: Dict[int, float] = {}
-        for k, v in self.op_rate_ewma.items():
-            d = v * (1.0 - alpha)
-            if d > 1e-3:
-                nxt_rates[k] = d
-        for k, h in ent_rates.items():
-            nxt_rates[k] = nxt_rates.get(k, 0.0) + alpha * h
-        self.op_rate_ewma = nxt_rates
-        # per-shard replica-service EWMA (keyed by shard): FINDs a shard
-        # serves from its read replicas are real load but invisible to the
-        # registry-keyed entry rates (the entry lives on the primary), so
-        # without this the balancer sees serving replicas as idle and
-        # churns moves against phantom imbalance.
-        nxt_rep: Dict[int, float] = {}
-        for s2, v in self.rep_rate_ewma.items():
-            d = v * (1.0 - alpha)
-            if d > 1e-3:
-                nxt_rep[s2] = d
-        for s2, h in rep_served.items():
-            nxt_rep[s2] = nxt_rep.get(s2, 0.0) + alpha * h
-        self.rep_rate_ewma = nxt_rep
-
-        # host->shard membership announcements join the routed stream
-        # here (after the shard outboxes, a deterministic position) so
-        # they are partitioned/retransmitted like any protocol message.
-        if self._ctrl_out:
-            new_msgs.extend(self._ctrl_out)
-            self._ctrl_out = []
-
-        # ------------------------------------------------ route (FIFO/pair)
-        pre_lens = [b.shape[0] for b in self.backlog]
-        if self.net is not None:
-            # reliable transport over the (possibly nemesis-perturbed)
-            # wire: loopback rows bypass it, everything else is
-            # sequenced, retransmitted and delivered exactly once in
-            # per-lane order. Runs even on quiet rounds so retransmit
-            # timers, acks and delayed frames keep moving.
-            self.net.route_round(self.backlog, new_msgs, self.round_no)
-        elif new_msgs:
-            allm = np.concatenate([ob for _, ob in new_msgs], axis=0)
-            for d in range(self.n):
-                mine = allm[allm[:, M.F_DST] == d]
-                if self.delay_prob > 0.0 and mine.size:
-                    # hold back whole (src,dst) channels — preserves pair
-                    # FIFO while exercising cross-pair reordering
-                    srcs = np.unique(mine[:, M.F_SRC])
-                    held = srcs[self.rng.random(srcs.shape) < self.delay_prob]
-                    hold_mask = np.isin(mine[:, M.F_SRC], held)
-                    later, now = mine[hold_mask], mine[~hold_mask]
-                    self.backlog[d] = np.concatenate(
-                        [self.backlog[d], now, later], axis=0)
-                else:
-                    self.backlog[d] = np.concatenate(
-                        [self.backlog[d], mine], axis=0)
-        self._membership_maintenance()
-        if self.durability is not None:
-            # journal the round per live shard: the inputs consumed (the
-            # feed discipline re-derives them from backlog + appends),
-            # the completions produced (replay audit), and the post-
-            # routing lane image. fsync'd before this round's effects
-            # become observable via next round's acks (§14).
+        with span("cluster.launch"):
+            self._apply_crash_plans()
+            down = self._down()
+            outs = []
+            client_feeds: List[np.ndarray] = []
             for s in range(self.n):
                 if s in down:
+                    outs.append(None)
+                    client_feeds.append(np.zeros((0, M.FIELDS), np.int32))
                     continue
-                self.durability.log_round(
-                    s, self.round_no,
-                    appends=self.backlog[s][pre_lens[s]:],
-                    client=client_feeds[s], comp=comp_by_shard[s],
-                    bg_phases=B.slot_phases(self.bgs[s]),
-                    epoch=int(np.asarray(self.states[s].epoch)),
-                    lanes=self._lane_image(s))
-                self.durability.maybe_snapshot(
-                    s, self.round_no, self.states[s], self.bgs[s],
-                    self.backlog[s], self._lane_image(s))
-        if self.trace_enabled:
-            # membership transitions are part of the replay witness: a
-            # run that joins/retires at a different round is not a replay
-            for ep, ev, sh in self.membership.log[self._mb_logged:]:
-                self.round_trace.append(
-                    f"r{self.round_no} mb {ev} s{sh} e{ep}")
-            self._mb_logged = len(self.membership.log)
-            self.round_trace.append(trace_entry(
-                self.round_no, self.last_completions, out_counts,
-                extra=sum(b.shape[0] for b in self.backlog)
-                + (self.net.in_flight() if self.net is not None else 0)))
+                # feed: backlog first (FIFO), bounded by in_cap
+                feed = self.backlog[s][:self.in_cap]
+                self.backlog[s] = self.backlog[s][self.in_cap:]
+                inbox = np.zeros((self.in_cap, M.FIELDS), np.int32)
+                inbox[:feed.shape[0]] = feed
+                client = np.zeros((0, M.FIELDS), np.int32)
+                client_feeds.append(client)
+                out = shard_round(self.states[s], self.bgs[s], s,
+                                  jnp.asarray(inbox),
+                                  jnp.asarray(client.reshape(0, M.FIELDS)),
+                                  cfg)
+                outs.append(out)
+
+        with span("cluster.harvest"):
+            ndone = 0
+            self.last_completions = []
+            new_msgs: List[np.ndarray] = []
+            out_counts: List[int] = []
+            comp_by_shard: List[np.ndarray] = []
+            ent_rates: Dict[int, int] = {}
+            rep_served: Dict[int, int] = {}
+            for s, out in enumerate(outs):
+                if out is None:                  # crashed: emitted nothing
+                    out_counts.append(0)
+                    comp_by_shard.append(np.zeros((0, 4), np.int32))
+                    continue
+                self.states[s] = out.state
+                self.bgs[s] = out.bg
+                ctr = np.asarray(out.counters)   # one pull for them all
+                add_counters(self.stats, ctr[None])
+                rh = int(ctr[CTR["rep_hits"]])
+                if rh:
+                    rep_served[s] = rep_served.get(s, 0) + rh
+                hits = np.asarray(out.ent_hits)
+                nz = np.nonzero(hits)[0]
+                if nz.size:
+                    kmax = np.asarray(out.state.registry.keymax)
+                    for e in nz:
+                        k = int(kmax[e])
+                        if k != ST_KEY:
+                            ent_rates[k] = ent_rates.get(k, 0) + int(hits[e])
+                cnt = int(ctr[CTR["out_count"]])
+                out_counts.append(cnt)
+                self.stats["max_outbox"] = max(self.stats["max_outbox"], cnt)
+                if cnt > cfg.mailbox_cap:
+                    # not an assert: under ``python -O`` a dropped message
+                    # (replicate/ack) would silently deadlock
+                    # run_until_quiet.
+                    raise OutboxOverflow(
+                        f"shard {s} emitted {cnt} messages in round "
+                        f"{self.round_no}, mailbox_cap={cfg.mailbox_cap}: "
+                        f"{cnt - cfg.mailbox_cap} rows dropped — raise "
+                        f"mailbox_cap or reduce the per-round feed")
+                ob = np.asarray(out.outbox)[:cnt]
+                if ob.size:
+                    new_msgs.append((s, ob))
+                    hops = ob[ob[:, M.F_KIND] == M.MSG_OP, M.F_X2]
+                    if hops.size:
+                        self.stats["max_hops"] = max(self.stats["max_hops"],
+                                                     int(hops.max()))
+                        self.stats["delegated"] += int(hops.size)
+                cs = np.asarray(out.comp_slot)
+                cv = np.asarray(out.comp_val)
+                cr = np.asarray(out.comp_src)
+                ck = np.asarray(out.comp_key)
+                done = cs >= 0
+                comp_by_shard.append(np.stack(
+                    [cs[done], cv[done], cr[done], ck[done]],
+                    axis=1).astype(np.int32))
+                for slot, val, src, key in zip(cs[done], cv[done], cr[done],
+                                               ck[done]):
+                    slot = int(slot)
+                    if int(key) != SH_KEY:
+                        # one RANGE item — accumulate, publication waits
+                        # for the terminal count (DESIGN.md §16)
+                        self._range_parts.setdefault(slot, []).append(
+                            (int(key), int(val)))
+                        continue
+                    if slot in self._range_ops:
+                        # terminal scan result: F_A is the total item count
+                        self._range_done[slot] = (int(val), int(src))
+                        continue
+                    self.results[slot] = int(val)
+                    self.result_src[slot] = int(src)
+                    self.last_completions.append((slot, int(val), int(src)))
+                    self._pending_ops.pop(slot, None)
+                    ndone += 1
+            ndone += self._publish_ranges()
+
+        with span("cluster.rates"):
+            # per-entry op-rate EWMA update (once per round): decay every
+            # tracked entry, add this round's hits, drop entries decayed to
+            # noise so the dict tracks only recently-active sublists.
+            alpha = 0.3
+            nxt_rates: Dict[int, float] = {}
+            for k, v in self.op_rate_ewma.items():
+                d = v * (1.0 - alpha)
+                if d > 1e-3:
+                    nxt_rates[k] = d
+            for k, h in ent_rates.items():
+                nxt_rates[k] = nxt_rates.get(k, 0.0) + alpha * h
+            self.op_rate_ewma = nxt_rates
+            # per-shard replica-service EWMA (keyed by shard): FINDs a shard
+            # serves from its read replicas are real load but invisible to
+            # the registry-keyed entry rates (the entry lives on the
+            # primary), so without this the balancer sees serving replicas
+            # as idle and churns moves against phantom imbalance.
+            nxt_rep: Dict[int, float] = {}
+            for s2, v in self.rep_rate_ewma.items():
+                d = v * (1.0 - alpha)
+                if d > 1e-3:
+                    nxt_rep[s2] = d
+            for s2, h in rep_served.items():
+                nxt_rep[s2] = nxt_rep.get(s2, 0.0) + alpha * h
+            self.rep_rate_ewma = nxt_rep
+
+        with span("cluster.route"):
+            # host->shard membership announcements join the routed stream
+            # here (after the shard outboxes, a deterministic position) so
+            # they are partitioned/retransmitted like any protocol message.
+            if self._ctrl_out:
+                new_msgs.extend(self._ctrl_out)
+                self._ctrl_out = []
+
+            pre_lens = [b.shape[0] for b in self.backlog]
+            if self.net is not None:
+                # reliable transport over the (possibly nemesis-perturbed)
+                # wire: loopback rows bypass it, everything else is
+                # sequenced, retransmitted and delivered exactly once in
+                # per-lane order. Runs even on quiet rounds so retransmit
+                # timers, acks and delayed frames keep moving.
+                self.net.route_round(self.backlog, new_msgs, self.round_no)
+            elif new_msgs:
+                allm = np.concatenate([ob for _, ob in new_msgs], axis=0)
+                for d in range(self.n):
+                    mine = allm[allm[:, M.F_DST] == d]
+                    if self.delay_prob > 0.0 and mine.size:
+                        # hold back whole (src,dst) channels — preserves pair
+                        # FIFO while exercising cross-pair reordering
+                        srcs = np.unique(mine[:, M.F_SRC])
+                        held = srcs[self.rng.random(srcs.shape)
+                                    < self.delay_prob]
+                        hold_mask = np.isin(mine[:, M.F_SRC], held)
+                        later, now = mine[hold_mask], mine[~hold_mask]
+                        self.backlog[d] = np.concatenate(
+                            [self.backlog[d], now, later], axis=0)
+                    else:
+                        self.backlog[d] = np.concatenate(
+                            [self.backlog[d], mine], axis=0)
+            self._membership_maintenance()
+            if self.durability is not None:
+                # journal the round per live shard: the inputs consumed (the
+                # feed discipline re-derives them from backlog + appends),
+                # the completions produced (replay audit), and the post-
+                # routing lane image. fsync'd before this round's effects
+                # become observable via next round's acks (§14).
+                for s in range(self.n):
+                    if s in down:
+                        continue
+                    self.durability.log_round(
+                        s, self.round_no,
+                        appends=self.backlog[s][pre_lens[s]:],
+                        client=client_feeds[s], comp=comp_by_shard[s],
+                        bg_phases=B.slot_phases(self.bgs[s]),
+                        epoch=int(np.asarray(self.states[s].epoch)),
+                        lanes=self._lane_image(s))
+                    self.durability.maybe_snapshot(
+                        s, self.round_no, self.states[s], self.bgs[s],
+                        self.backlog[s], self._lane_image(s))
+            if self.trace_enabled:
+                # membership transitions are part of the replay witness: a
+                # run that joins/retires at a different round is not a replay
+                for ep, ev, sh in self.membership.log[self._mb_logged:]:
+                    self.round_trace.append(
+                        f"r{self.round_no} mb {ev} s{sh} e{ep}")
+                self._mb_logged = len(self.membership.log)
+                self.round_trace.append(trace_entry(
+                    self.round_no, self.last_completions, out_counts,
+                    extra=sum(b.shape[0] for b in self.backlog)
+                    + (self.net.in_flight() if self.net is not None else 0)))
         self.round_no += 1
         self.stats["rounds"] += 1
         return ndone
