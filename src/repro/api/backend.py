@@ -400,7 +400,8 @@ class ShardMapBackend:
         self.stats = {"max_outbox": 0, "max_hops": 0, "rounds": 0,
                       "fast_hits": 0, "mut_hits": 0, "delegated": 0,
                       "move_hits": 0, "blk_hits": 0, "max_bg_active": 0,
-                      "rep_hits": 0, "range_hits": 0, "serial_rows": 0}
+                      "rep_hits": 0, "range_hits": 0, "serial_rows": 0,
+                      "blk_rows": 0}
         # RANGE reassembly (DESIGN.md §16) — same count-gated protocol
         # as ``Cluster``: items and the terminal count ride separate
         # completion rows (and, across shards, separate transport lanes),

@@ -9,7 +9,8 @@ of truth:
 
   * ``refresh_blocks`` runs at round *start* (before anything mutates) and
     rebuilds only rows that are dirty AND owned-and-live. The rebuild is
-    one lock-step chain walk across all M entries; a row validates only
+    a lock-step chain walk over those rows alone, compacted into chunks
+    of up to 128 lanes; a row validates only
     when its walk saw exclusively local, non-moving (newLoc == null),
     non-switched (stCt >= 0) nodes, collected at most C *live* keys, and
     terminated at the entry's *registered*, unmarked SubTail. Marked
@@ -64,16 +65,25 @@ def invalidate_entry(blk: Blocks, e, when=True) -> Blocks:
     return blk._replace(valid=blk.valid.at[at].set(False, mode="drop"))
 
 
-def refresh_blocks(state: ShardState, me, cfg: DiLiConfig) -> ShardState:
+# Lanes of one chunk of the rebuild walk: the TPU's vector lane width.
+_LANES = 128
+
+
+def refresh_blocks(state: ShardState, me, cfg: DiLiConfig):
     """Rebuild every dirty, owned, live registry entry's packed block.
 
-    One lock-step walk over all M entries with a per-row write cursor:
-    live keys land at their cursor column, marked tombstones and in-chain
-    SubHeads are stepped over without writing (matching ``chain_keys`` /
-    the serial traversal's view). Cost is bounded by the longest owned
-    chain (early exit), the same shape as ``probe_batch``'s sweep — but
-    amortized: a row rebuilt once serves every subsequent round until a
-    writer dirties it.
+    Returns ``(state, rows)``, ``rows`` the number of entries rebuilt.
+    The rows that need a rebuild are compacted, in entry order, into
+    chunks of up to 128 lanes, and each chunk runs one lock-step chain
+    walk with a per-row write cursor: live keys land at their cursor
+    column, marked tombstones and in-chain SubHeads are stepped over
+    without writing (matching ``chain_keys`` / the serial traversal's
+    view). A chunk's finished rows are scattered back once. Rows never
+    interact in the walk, so each row comes out as a walk over all M
+    entries would leave it. Cost scales with the dirty rows, not with M:
+    one chunk in the usual round, none in a round with nothing dirty,
+    each bounded by its longest chain (early exit) — and amortized: a row
+    rebuilt once serves every subsequent round until a writer dirties it.
     """
     pool = state.pool
     reg = state.registry
@@ -91,20 +101,23 @@ def refresh_blocks(state: ShardState, me, cfg: DiLiConfig) -> ShardState:
         (refs.ref_sid(sh) == me) & (state.stct[slot] >= 0) & \
         refs.is_null(pool.newloc[head_idx])
     need = live & (~blk.valid)
+    n_need = jnp.sum(need, dtype=jnp.int32)
 
-    keys0 = jnp.where(need[:, None], ST_KEY, blk.keys)
-    idx0 = jnp.where(need[:, None], 0, blk.idx)
+    lanes = min(_LANES, m)
+    n_pad = -(-m // lanes) * lanes
+    # needing rows first, in entry order; m pads (dropped by the scatters)
+    todo = jnp.nonzero(need, size=n_pad, fill_value=m)[0].astype(jnp.int32)
     st_ref = refs.unmarked(reg.subtail)
-    rows_ = jnp.arange(m, dtype=jnp.int32)
+    cols = jnp.arange(c, dtype=jnp.int32)
     # chain steps, not live keys: tombstones stretch the walk past C
     bound = int(cfg.max_scan)
 
     def w_cond(carry):
-        i, keys, idxs, col, cur, collecting, good = carry
+        i, keys, idxs, col, cur, collecting, good, st = carry
         return (i < bound) & jnp.any(collecting)
 
     def w_body(carry):
-        i, keys, idxs, col, cur, collecting, good = carry
+        i, keys, idxs, col, cur, collecting, good, st = carry
         ci = jnp.clip(refs.ref_idx(cur).astype(jnp.int32), 0, n - 1)
         local = refs.ref_sid(cur) == me
         word = pool.nxt[ci]
@@ -117,7 +130,7 @@ def refresh_blocks(state: ShardState, me, cfg: DiLiConfig) -> ShardState:
         # the terminating ST must be the *registered* subtail, unmarked —
         # a mid-Split ST (or a merge-neutralized one) fails the identity
         # check and the row stays invalid until the registry catches up
-        reach_ok = at_st & (~marked) & (refs.unmarked(cur) == st_ref)
+        reach_ok = at_st & (~marked) & (refs.unmarked(cur) == st)
         # marked non-ST nodes and in-chain SubHeads are logically absent:
         # step over them, exactly as chain_keys / the serial walk do
         hop = (k == SH_KEY) | (marked & ~at_st)
@@ -126,24 +139,44 @@ def refresh_blocks(state: ShardState, me, cfg: DiLiConfig) -> ShardState:
             | (at_st & ~reach_ok) | (want_write & (col >= c))
         write = collecting & (~bad) & want_write
 
-        at_col = jnp.where(write, col, c)          # col == C drops
-        keys = keys.at[rows_, at_col].set(k, mode="drop")
-        idxs = idxs.at[rows_, at_col].set(ci, mode="drop")
+        # each writing lane sets its cursor column: a dense select, as a
+        # TPU scatter into the [lanes, C] buffer costs ~8 us a step
+        at = cols[None, :] == jnp.where(write, col, c)[:, None]
+        keys = jnp.where(at, k[:, None], keys)
+        idxs = jnp.where(at, ci[:, None], idxs)
         good = good | (collecting & reach_ok)
         collecting = collecting & (~bad) & (~reach_ok)
         col = col + write.astype(jnp.int32)
         cur = jnp.where(collecting, word, cur)
-        return i + 1, keys, idxs, col, cur, collecting, good
+        return i + 1, keys, idxs, col, cur, collecting, good, st
 
-    init = (jnp.zeros((), jnp.int32), keys0, idx0,
-            jnp.zeros((m,), jnp.int32), pool.nxt[head_idx], need,
-            jnp.zeros((m,), bool))
-    _, keys, idxs, _, _, _, good = jax.lax.while_loop(
-        w_cond, w_body, init)
+    def c_cond(carry):
+        j = carry[0]
+        return j * lanes < n_need
+
+    def c_body(carry):
+        j, keys, idxs, good = carry
+        rows = jax.lax.dynamic_slice(todo, (j * lanes,), (lanes,))
+        r = jnp.clip(rows, 0, m - 1)
+        init = (jnp.zeros((), jnp.int32),
+                jnp.full((lanes, c), ST_KEY, keys.dtype),
+                jnp.zeros((lanes, c), idxs.dtype),
+                jnp.zeros((lanes,), jnp.int32), pool.nxt[head_idx[r]],
+                rows < m, jnp.zeros((lanes,), bool), st_ref[r])
+        _, k_ch, i_ch, _, _, _, g_ch, _ = jax.lax.while_loop(
+            w_cond, w_body, init)
+        return (j + 1, keys.at[rows].set(k_ch, mode="drop"),
+                idxs.at[rows].set(i_ch, mode="drop"),
+                good.at[rows].set(g_ch, mode="drop"))
+
+    _, keys, idxs, good = jax.lax.while_loop(
+        c_cond, c_body, (jnp.zeros((), jnp.int32), blk.keys, blk.idx,
+                         jnp.zeros((m,), bool)))
     # rows still collecting at the bound never reached their subtail (or
     # overflowed C live keys): not good.
     valid = (blk.valid | good) & live
-    return state._replace(blk=Blocks(keys=keys, idx=idxs, valid=valid))
+    return state._replace(blk=Blocks(keys=keys, idx=idxs, valid=valid)), \
+        n_need
 
 
 def probe_blocks(state: ShardState, entry, sh_ref, q, me, cfg: DiLiConfig):

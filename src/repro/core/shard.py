@@ -26,6 +26,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from . import batch_apply as BA
 from . import bg as B
@@ -64,6 +65,9 @@ class RoundOut(NamedTuple):
                              # arrivals + replica serves). The host feeds
                              # these into the per-entry op-rate EWMA the
                              # balancer's load model reads.
+    harvest: jnp.ndarray     # int32 vector — everything the host driver
+                             # reads of the round, packed (``Harvest``
+                             # layout) so it crosses in one transfer
 
 
 # The scalar counters of one shard-round, in ``RoundOut.counters`` order.
@@ -81,10 +85,40 @@ COUNTERS = (
                     # pre-pass (vs the serial chain walk; DESIGN.md §16)
     "bg_active",    # background slots busy after the round
     "serial_rows",  # rows the serial loop executed
+    "blk_rows",     # packed blocks ``refresh_blocks`` rebuilt (DESIGN.md §12)
 )
 CTR = {name: i for i, name in enumerate(COUNTERS)}
 _SUMMED = ("fast_hits", "mut_hits", "move_hits", "blk_hits", "rep_hits",
-           "range_hits", "serial_rows")
+           "range_hits", "serial_rows", "blk_rows")
+
+
+class Harvest(NamedTuple):
+    """What ``Cluster.step`` reads of one shard-round, as ``RoundOut.
+    harvest`` packs it: these fields of ``RoundOut`` (``keymax`` is the
+    post-round ``state.registry.keymax``), flattened in this order."""
+    counters: np.ndarray     # [len(COUNTERS)]
+    ent_hits: np.ndarray     # [M]
+    keymax: np.ndarray       # [M]
+    outbox: np.ndarray       # [mailbox_cap, FIELDS]
+    comp_slot: np.ndarray    # [K] — K the round's inbox + client rows
+    comp_val: np.ndarray     # [K]
+    comp_src: np.ndarray     # [K]
+    comp_key: np.ndarray     # [K]
+
+
+def unpack_harvest(vec: np.ndarray, cfg: DiLiConfig) -> Harvest:
+    """Split a pulled ``RoundOut.harvest`` into its fields (views)."""
+    m = cfg.max_sublists
+    fixed = len(COUNTERS) + 2 * m + cfg.mailbox_cap * M.FIELDS
+    k = (vec.shape[0] - fixed) // 4
+    shapes = ((len(COUNTERS),), (m,), (m,), (cfg.mailbox_cap, M.FIELDS),
+              (k,), (k,), (k,), (k,))
+    parts, at = [], 0
+    for shape in shapes:
+        size = int(np.prod(shape))
+        parts.append(vec[at:at + size].reshape(shape))
+        at += size
+    return Harvest(*parts)
 
 
 def add_counters(stats, ctr) -> None:
@@ -196,7 +230,9 @@ def shard_round(state: ShardState, bg: B.BgTable, me, inbox, client,
     # With both off, the mirror stays all-invalid and costs nothing.
     with jax.named_scope("round.blocks"):
         if cfg.block_probe or cfg.replication or cfg.range_scan:
-            state = BL.refresh_blocks(state, me, cfg)
+            state, blk_rows = BL.refresh_blocks(state, me, cfg)
+        else:
+            blk_rows = jnp.zeros((), jnp.int32)
 
     # RANGE gather pre-pass (DESIGN.md §16): serve scan cursors whose
     # covering entry has a valid packed block, against the same
@@ -368,10 +404,14 @@ def shard_round(state: ShardState, bg: B.BgTable, me, inbox, client,
             rep_hits=jnp.sum(rep_elig),
             range_hits=range_hits,
             bg_active=jnp.sum(bg.phase != B.BG_IDLE),
-            serial_rows=n_live)
+            serial_rows=n_live,
+            blk_rows=blk_rows)
+        counters = jnp.stack([counters[n] for n in COUNTERS]).astype(
+            jnp.int32)
+        harvest = jnp.concatenate([
+            counters, ent_hits, state.registry.keymax, outbox.reshape(-1),
+            cslots, cvals, csrcs, ckeys]).astype(jnp.int32)
         return RoundOut(state=state, bg=bg, outbox=outbox, out_count=count,
                         comp_slot=cslots, comp_val=cvals, comp_src=csrcs,
-                        comp_key=ckeys,
-                        counters=jnp.stack([counters[n] for n in COUNTERS])
-                        .astype(jnp.int32),
-                        ent_hits=ent_hits)
+                        comp_key=ckeys, counters=counters,
+                        ent_hits=ent_hits, harvest=harvest)

@@ -39,7 +39,7 @@ from .durability import Durability, wal
 from .membership import (Membership, epoch_broadcast, moves_targeting,
                          owned_entry_count)
 from .net import Nemesis, NemesisConfig, Transport, trace_entry
-from .shard import CTR, add_counters, shard_round
+from .shard import CTR, add_counters, shard_round, unpack_harvest
 from .spans import span
 from .types import (DiLiConfig, KEY_MAX, KEY_MIN, OP_FIND, OP_INSERT,
                     OP_REMOVE, SH_KEY, ST_KEY, ShardState, init_shard)
@@ -356,7 +356,8 @@ class Cluster:
         self.stats = {"max_outbox": 0, "max_hops": 0, "rounds": 0,
                       "fast_hits": 0, "mut_hits": 0, "delegated": 0,
                       "move_hits": 0, "blk_hits": 0, "max_bg_active": 0,
-                      "rep_hits": 0, "range_hits": 0, "serial_rows": 0}
+                      "rep_hits": 0, "range_hits": 0, "serial_rows": 0,
+                      "blk_rows": 0}
         # per-entry op-rate EWMA (keyed by entry keymax), fed from every
         # round's RoundOut.ent_hits — the load signal the balancer's
         # op-rate model and hot-entry replication stage read (§15). Decays
@@ -619,6 +620,9 @@ class Cluster:
                                   jnp.asarray(inbox),
                                   jnp.asarray(client.reshape(0, M.FIELDS)),
                                   cfg)
+                # the harvest reads this one vector: start its copy now, so
+                # it runs as soon as this shard's program ends
+                out.harvest.copy_to_host_async()
                 outs.append(out)
 
         with span("cluster.harvest"):
@@ -636,15 +640,16 @@ class Cluster:
                     continue
                 self.states[s] = out.state
                 self.bgs[s] = out.bg
-                ctr = np.asarray(out.counters)   # one pull for them all
+                h = unpack_harvest(np.asarray(out.harvest), cfg)  # one pull
+                ctr = h.counters
                 add_counters(self.stats, ctr[None])
                 rh = int(ctr[CTR["rep_hits"]])
                 if rh:
                     rep_served[s] = rep_served.get(s, 0) + rh
-                hits = np.asarray(out.ent_hits)
+                hits = h.ent_hits
                 nz = np.nonzero(hits)[0]
                 if nz.size:
-                    kmax = np.asarray(out.state.registry.keymax)
+                    kmax = h.keymax
                     for e in nz:
                         k = int(kmax[e])
                         if k != ST_KEY:
@@ -661,7 +666,7 @@ class Cluster:
                         f"{self.round_no}, mailbox_cap={cfg.mailbox_cap}: "
                         f"{cnt - cfg.mailbox_cap} rows dropped — raise "
                         f"mailbox_cap or reduce the per-round feed")
-                ob = np.asarray(out.outbox)[:cnt]
+                ob = h.outbox[:cnt]
                 if ob.size:
                     new_msgs.append((s, ob))
                     hops = ob[ob[:, M.F_KIND] == M.MSG_OP, M.F_X2]
@@ -669,10 +674,8 @@ class Cluster:
                         self.stats["max_hops"] = max(self.stats["max_hops"],
                                                      int(hops.max()))
                         self.stats["delegated"] += int(hops.size)
-                cs = np.asarray(out.comp_slot)
-                cv = np.asarray(out.comp_val)
-                cr = np.asarray(out.comp_src)
-                ck = np.asarray(out.comp_key)
+                cs, cv, cr, ck = (h.comp_slot, h.comp_val, h.comp_src,
+                                  h.comp_key)
                 done = cs >= 0
                 comp_by_shard.append(np.stack(
                     [cs[done], cv[done], cr[done], ck[done]],
