@@ -25,6 +25,7 @@ The contract (``Backend`` protocol):
 from __future__ import annotations
 
 from collections import deque
+from functools import lru_cache
 from typing import Dict, List, Optional, Protocol, Sequence, Tuple
 
 import numpy as np
@@ -38,7 +39,8 @@ from repro.core import replica as R
 from repro.core.distributed import ROUTED_HOPS, ROUTED_LIVE, ROUTED_OPS
 from repro.core.membership import (Membership, epoch_row, moves_targeting,
                                    owned_entry_count)
-from repro.core.shard import CTR, add_counters
+from repro.core.net import trace_entry
+from repro.core.shard import CTR, add_counters, unpack_harvest
 from repro.core.sim import (Cluster, OpIdAllocator, OutboxOverflow,
                             chain_keys, global_keys, make_op_row,
                             materialize_ops, registry_entries,
@@ -100,6 +102,45 @@ class Backend(Protocol):
                      target: int = -1) -> bool: ...
 
     def replica_sets(self) -> Dict[int, Tuple[int, int, List[int]]]: ...
+
+
+@lru_cache(maxsize=None)
+def _slot_command(fn, cfg, sharding):
+    """A one-shard command ``fn(tree, [cfg,] *args) -> (tree, ok)``
+    applied to slot ``s`` of a tree stacked over the mesh, as one jitted
+    call: one dispatch, where slicing and updating each leaf took two."""
+    import jax
+
+    def run(stacked, s, *args):
+        tree_map = jax.tree_util.tree_map
+        one = tree_map(lambda col: col[s], stacked)
+        one, ok = fn(one, *args) if cfg is None else fn(one, cfg, *args)
+        stacked = tree_map(lambda col, leaf: col.at[s].set(leaf), stacked,
+                           one)
+        return jax.lax.with_sharding_constraint(stacked, sharding), ok
+
+    return jax.jit(run)
+
+
+@lru_cache(maxsize=None)
+def _packer(sharding):
+    """One jitted call that lays the leaves of a tree stacked over the
+    mesh side by side as int32 ``[S, N]`` (bools widened, 32-bit types
+    bitcast), so that a host snapshot is one transfer a shard and not one
+    a leaf; ``ShardMapBackend._unstack`` undoes it."""
+    import jax
+    import jax.numpy as jnp
+
+    def pack(stacked):
+        cols = []
+        for x in jax.tree_util.tree_leaves(stacked):
+            x = x.reshape(x.shape[0], -1)
+            cols.append(x.astype(jnp.int32) if x.dtype == jnp.bool_
+                        else jax.lax.bitcast_convert_type(x, jnp.int32))
+        return jax.lax.with_sharding_constraint(
+            jnp.concatenate(cols, axis=1), sharding)
+
+    return jax.jit(pack)
 
 
 class LocalBackend:
@@ -287,10 +328,13 @@ class ShardMapBackend:
     total outbox count exceeding ``mailbox_cap`` first — which raises
     ``OutboxOverflow`` exactly like ``Cluster.step``.
 
-    The balance surface works on host snapshots of the stacked device
-    state (pulled lazily, invalidated each round); Split/Move/Merge are
-    queued by editing the stacked ``BgState`` in place, and execute inside
-    the jitted round like any other background phase.
+    A round is one launch and one pull: the round's packed harvest
+    (``make_dili_round``), whose copy starts as the call returns. The
+    balance surface works on host snapshots of the stacked device state
+    and BgTables, each pulled as one packed array on first use after a
+    change (a round, a command, a crash or restart); Split/Move/Merge are queued
+    by editing slot ``s`` of the stacked table in one jitted call, and
+    execute inside the round like any other background phase.
     """
 
     def __init__(self, cfg: DiLiConfig, *, mesh=None,
@@ -299,10 +343,10 @@ class ShardMapBackend:
                  net_window: int = 4096,
                  key_lo: int = KEY_MIN, key_hi: int = KEY_MAX,
                  initial_shards: Optional[int] = None,
-                 durability=None):
+                 durability=None, trace: Optional[bool] = None):
         import jax
         import jax.numpy as jnp
-        from jax.sharding import Mesh
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec
         from repro.core.distributed import (make_dili_round,
                                             make_dili_round_hostroute,
                                             stack_states)
@@ -320,6 +364,9 @@ class ShardMapBackend:
             mesh = Mesh(devs[:cfg.num_shards].reshape(cfg.num_shards),
                         ("shard",))
         self.mesh = mesh
+        # host arrays go straight to the round's layout, one shard a device
+        self._sharding = NamedSharding(mesh,
+                                       PartitionSpec(tuple(mesh.axis_names)))
         self.cap_pair = int(cap_pair if cap_pair is not None
                             else cfg.mailbox_cap)
         if self.cap_pair < cfg.mailbox_cap:
@@ -338,7 +385,8 @@ class ShardMapBackend:
                        initial_shards=initial_shards)
         self.membership = boot.membership
         self._mb_logged = 0
-        self._states, self._bgs = stack_states(boot.states, boot.bgs)
+        self._states, self._bgs = self._put(stack_states(boot.states,
+                                                         boot.bgs))
         # same child-stream layout as Cluster: (delay, nemesis, balancer)
         self.seed = seed
         root = np.random.SeedSequence(seed)
@@ -346,6 +394,10 @@ class ShardMapBackend:
         self.balancer_rng = np.random.default_rng(balancer_ss)
         self.nemesis_config = nemesis
         self.net = None
+        # per-round observable outcome, as ``Cluster.round_trace``: on by
+        # default for nemesis runs, off on the clean path
+        self.trace_enabled = (nemesis is not None) if trace is None \
+            else bool(trace)
         self.round_trace: List[str] = []
         if nemesis is not None:
             # nemesis lives on the wire between outboxes and inboxes, so
@@ -365,12 +417,15 @@ class ShardMapBackend:
             self.in_cap = cfg.num_shards * self.cap_pair
             # the persistent device inbox feeds the all_to_all round;
             # the hostroute path builds a fresh host inbox each round
-            self._inbox = jnp.zeros(
-                (cfg.num_shards, self.in_cap, M.FIELDS), jnp.int32)
+            self._inbox = self._put(np.zeros(
+                (cfg.num_shards, self.in_cap, M.FIELDS), np.int32))
         self._inflight_msgs = 0
         self._queues: List[deque] = [deque() for _ in range(cfg.num_shards)]
         self._ids = OpIdAllocator()
         self._host_states: Optional[list] = None
+        self._host_bgs: Optional[list] = None
+        # any background slot busy; None = unknown, read off the snapshot
+        self._bg_busy: Optional[bool] = False
         self.round_no = 0
         # durability + crash plans (DESIGN.md §14): same semantics as
         # Cluster — crashes ride the nemesis config (hostroute path), so
@@ -401,7 +456,7 @@ class ShardMapBackend:
                       "fast_hits": 0, "mut_hits": 0, "delegated": 0,
                       "move_hits": 0, "blk_hits": 0, "max_bg_active": 0,
                       "rep_hits": 0, "range_hits": 0, "serial_rows": 0,
-                      "blk_rows": 0}
+                      "blk_rows": 0, "routed_rows": 0}
         # RANGE reassembly (DESIGN.md §16) — same count-gated protocol
         # as ``Cluster``: items and the terminal count ride separate
         # completion rows (and, across shards, separate transport lanes),
@@ -565,7 +620,8 @@ class ShardMapBackend:
         self._bgs = tree_map(
             lambda col, leaf: col.at[s].set(jnp.asarray(leaf)),
             self._bgs, bg)
-        self._host_states = None
+        self._drop_snapshots()
+        self._bg_busy = None
 
     def _apply_crash_plans(self) -> None:
         """Same top-of-round ordering as ``Cluster._apply_crash_plans``:
@@ -615,14 +671,14 @@ class ShardMapBackend:
                 f"{self.round_no}, mailbox_cap={self.cfg.mailbox_cap} — "
                 f"raise mailbox_cap or reduce the per-round feed")
 
-    def _harvest(self, cs, cv, cr, ck) -> List[Completion]:
+    def _harvest(self, h) -> List[Completion]:
         """Completions of one round as (op_id, result, src) with id
-        recycling — shared by both round paths. ``ck`` is the comp_key
-        lane: SH_KEY marks a scalar completion; a real key marks a RANGE
-        item row (key, value) for the slot's scan (DESIGN.md §16)."""
+        recycling — shared by both round paths. ``h`` is the round's
+        stacked ``Harvest``; its ``comp_key`` lane holds SH_KEY for a
+        scalar completion, a real key for a RANGE item row (key, value)
+        of the slot's scan (DESIGN.md §16)."""
         comps: List[Completion] = []
-        cs, cv = np.asarray(cs), np.asarray(cv)
-        cr, ck = np.asarray(cr), np.asarray(ck)
+        cs, cv, cr, ck = h.comp_slot, h.comp_val, h.comp_src, h.comp_key
         done = cs >= 0
         for slot, val, src, key in zip(cs[done], cv[done], cr[done],
                                        ck[done]):
@@ -650,24 +706,33 @@ class ShardMapBackend:
             self._ids.release(slot)
         return comps
 
-    def _update_op_rates(self, ent_hits, rep_hits=None) -> None:
+    def _fold_counters(self, h) -> List[int]:
+        """Fold the round's counters into ``stats`` and the op-rate model,
+        after the overflow check; returns each shard's outbox count."""
+        ctr = h.counters
+        out_counts = [int(c) for c in ctr[:, CTR["out_count"]]]
+        self._check_overflow(out_counts)
+        add_counters(self.stats, ctr)
+        self._bg_busy = bool(ctr[:, CTR["bg_active"]].any())
+        self._update_op_rates(h.ent_hits, h.keymax, ctr[:, CTR["rep_hits"]])
+        return out_counts
+
+    def _update_op_rates(self, hits, kmax, rep_hits) -> None:
         """Per-entry op-rate EWMA, mirroring ``Cluster.step``'s update
         (same alpha/prune so the differential harness sees one model):
-        decay every tracked entry, add this round's per-shard hits keyed
-        by registry keymax, drop entries decayed to noise. ``rep_hits``
+        decay every tracked entry, add this round's per-shard hits
+        (``hits`` [S, M]) keyed by the post-round registry keymax
+        (``kmax`` [S, M]), drop entries decayed to noise. ``rep_hits``
         (per-shard replica-served FIND counts, [S]) feeds the per-shard
         ``rep_rate_ewma`` the balancer folds into shard load — replica
         service is invisible to the registry-keyed rates (the entry lives
         on the primary), and an uncorrected model reads serving replicas
         as idle and churns moves against phantom imbalance."""
-        hits = np.asarray(ent_hits)                       # [S, M]
         ent_rates: Dict[int, int] = {}
-        if hits.any():
-            kmax = np.asarray(self._states.registry.keymax)   # [S, M]
-            for s, e in zip(*np.nonzero(hits)):
-                k = int(kmax[s, e])
-                if k != ST_KEY:
-                    ent_rates[k] = ent_rates.get(k, 0) + int(hits[s, e])
+        for s, e in zip(*np.nonzero(hits)):
+            k = int(kmax[s, e])
+            if k != ST_KEY:
+                ent_rates[k] = ent_rates.get(k, 0) + int(hits[s, e])
         alpha = 0.3
         nxt: Dict[int, float] = {}
         for k, v in self.op_rate_ewma.items():
@@ -682,16 +747,31 @@ class ShardMapBackend:
             d = v * (1.0 - alpha)
             if d > 1e-3:
                 nxt_rep[s] = d
-        if rep_hits is not None:
-            for s, h in enumerate(np.asarray(rep_hits)):
-                if h:
-                    nxt_rep[s] = nxt_rep.get(s, 0.0) + alpha * int(h)
+        for s, h in enumerate(rep_hits):
+            if h:
+                nxt_rep[s] = nxt_rep.get(s, 0.0) + alpha * int(h)
         self.rep_rate_ewma = nxt_rep
+
+    def _trace_round(self, comps, out_counts, extra: int) -> None:
+        """Append the round to ``round_trace`` as ``Cluster.step`` does:
+        the round's membership transitions, then its outcome."""
+        for ep, ev, sh in self.membership.log[self._mb_logged:]:
+            self.round_trace.append(f"r{self.round_no} mb {ev} s{sh} e{ep}")
+        self._mb_logged = len(self.membership.log)
+        self.round_trace.append(trace_entry(self.round_no, comps, out_counts,
+                                            extra=extra))
+
+    def _put(self, x):
+        """A stacked host array onto the mesh, one shard a device."""
+        return self._jax.device_put(x, self._sharding)
+
+    def _drop_snapshots(self) -> None:
+        self._host_states = None
+        self._host_bgs = None
 
     def _step_hostroute(self) -> List[Completion]:
         """One round on the nemesis path: device round (no all_to_all),
         host-side transport routing of the raw outboxes."""
-        from repro.core.net import trace_entry
         with span("shardmap.launch"):
             if self._crash_plans:
                 self._apply_crash_plans()
@@ -702,29 +782,23 @@ class ShardMapBackend:
                 feed = self._net_backlog[s][:self.in_cap]
                 self._net_backlog[s] = self._net_backlog[s][self.in_cap:]
                 inbox[s, :feed.shape[0]] = feed
-            out = self._rnd(self._states, self._bgs,
-                            self._jnp.asarray(inbox),
-                            self._jnp.asarray(client))
-            self._states, self._bgs, outbox, cs, cv, cr, ck, ctr, \
-                ent_hits = out
-            self._host_states = None
+            self._states, self._bgs, vec = self._rnd(
+                self._states, self._bgs, self._put(inbox), self._put(client))
+            vec.copy_to_host_async()
+            self._drop_snapshots()
         with span("shardmap.harvest"):
-            ctr = np.asarray(ctr)
-            out_counts = [int(c) for c in ctr[:, CTR["out_count"]]]
-            self._check_overflow(out_counts)
-            add_counters(self.stats, ctr)
-            self._update_op_rates(ent_hits, ctr[:, CTR["rep_hits"]])
-            outbox = np.asarray(outbox)
+            h = unpack_harvest(np.asarray(vec), self.cfg)   # one pull
+            out_counts = self._fold_counters(h)
             per_src = []
             for s in range(self.n):
-                rows = outbox[s][:out_counts[s]]
+                rows = h.outbox[s][:out_counts[s]]
                 hops = rows[rows[:, M.F_KIND] == M.MSG_OP, M.F_X2]
                 if hops.size:
                     self.stats["max_hops"] = max(self.stats["max_hops"],
                                                  int(hops.max()))
                     self.stats["delegated"] += int(hops.size)
                 per_src.append((s, rows))
-            comps = self._harvest(cs, cv, cr, ck)
+            comps = self._harvest(h)
         with span("shardmap.route"):
             pre_lens = [b.shape[0] for b in self._net_backlog]
             self.net.route_round(self._net_backlog, per_src, self.round_no)
@@ -734,17 +808,16 @@ class ShardMapBackend:
                 # Cluster.step): the client feed consumed, the routed
                 # appends, completions + bg phases + epoch (replay audit),
                 # post-routing lane image.
-                cs_h = np.asarray(cs)
-                cv_h, cr_h = np.asarray(cv), np.asarray(cr)
-                ck_h = np.asarray(ck)
                 phases = np.asarray(self._bgs.phase)
                 epochs = np.asarray(self._states.epoch)
                 for s in range(self.n):
                     if s in down:
                         continue
-                    done = cs_h[s] >= 0
-                    comp = np.stack([cs_h[s][done], cv_h[s][done],
-                                     cr_h[s][done], ck_h[s][done]],
+                    done = h.comp_slot[s] >= 0
+                    comp = np.stack([h.comp_slot[s][done],
+                                     h.comp_val[s][done],
+                                     h.comp_src[s][done],
+                                     h.comp_key[s][done]],
                                     axis=1).astype(np.int32)
                     lanes = self.net.export_shard_lanes(s)
                     self.durability.log_round(
@@ -762,14 +835,11 @@ class ShardMapBackend:
                         self.durability.snapshot_now(
                             s, self.round_no, st, bg, self._net_backlog[s],
                             lanes)
-            for ep, ev, sh in self.membership.log[self._mb_logged:]:
-                self.round_trace.append(
-                    f"r{self.round_no} mb {ev} s{sh} e{ep}")
-            self._mb_logged = len(self.membership.log)
-            self.round_trace.append(trace_entry(
-                self.round_no, comps, out_counts,
-                extra=sum(b.shape[0] for b in self._net_backlog)
-                + self.net.in_flight()))
+            if self.trace_enabled:
+                self._trace_round(comps, out_counts,
+                                  extra=sum(b.shape[0]
+                                            for b in self._net_backlog)
+                                  + self.net.in_flight())
         self.round_no += 1
         self.stats["rounds"] += 1
         return comps
@@ -779,27 +849,30 @@ class ShardMapBackend:
             return self._step_hostroute()
         with span("shardmap.launch"):
             client = self._feed_client()
-            out = self._rnd(self._states, self._bgs, self._inbox,
-                            self._jnp.asarray(client))
-            self._states, self._bgs, self._inbox, cs, cv, cr, ck, ctr, \
-                ent_hits = out
-            self._host_states = None
+            self._states, self._bgs, self._inbox, vec = self._rnd(
+                self._states, self._bgs, self._inbox, self._put(client))
+            # the harvest reads this one array: start its copy now, so it
+            # runs as soon as the round ends
+            vec.copy_to_host_async()
+            self._drop_snapshots()
         with span("shardmap.harvest"):
-            # per-shard round counters computed on-device, the routed
-            # inbox's after the shard's own (the inbox itself never
-            # crosses to host on the hot path; see make_dili_round)
-            ctr = np.asarray(ctr)
-            self._check_overflow([int(c) for c in ctr[:, CTR["out_count"]]])
-            add_counters(self.stats, ctr)
-            self._inflight_msgs = int(ctr[:, ROUTED_LIVE].sum())
-            self._update_op_rates(ent_hits, ctr[:, CTR["rep_hits"]])
-            delegated = int(ctr[:, ROUTED_OPS].sum())
+            # the routed inbox itself never crosses to the host: its
+            # counts ride at the end of each shard's harvest row
+            vec = np.asarray(vec)                 # the round's one pull
+            h = unpack_harvest(vec[:, :ROUTED_LIVE], self.cfg)
+            out_counts = self._fold_counters(h)
+            live = int(vec[:, ROUTED_LIVE].sum())
+            self._inflight_msgs = live
+            self.stats["routed_rows"] += live
+            delegated = int(vec[:, ROUTED_OPS].sum())
             if delegated:
                 self.stats["delegated"] += delegated
                 self.stats["max_hops"] = max(self.stats["max_hops"],
-                                             int(ctr[:, ROUTED_HOPS].max()))
-            comps = self._harvest(cs, cv, cr, ck)
+                                             int(vec[:, ROUTED_HOPS].max()))
+            comps = self._harvest(h)
             self._membership_maintenance()
+            if self.trace_enabled:
+                self._trace_round(comps, out_counts, extra=live)
         self.round_no += 1
         self.stats["rounds"] += 1
         return comps
@@ -816,27 +889,45 @@ class ShardMapBackend:
                 return False
         elif self._inflight_msgs:
             return False
-        phases = np.asarray(self._bgs.phase)
-        return bool((phases == B.BG_IDLE).all())
+        if self._bg_busy is None:
+            self._bg_busy = any(B.any_active(bg) for bg in self.bgs)
+        return not self._bg_busy
 
     def registry_entries(self, shard: int = 0) -> List[RegEntry]:
-        return registry_entries(self.states[shard])
+        if self._host_states is not None:
+            return registry_entries(self._host_states[shard].registry)
+        # the registries alone, not a whole snapshot
+        return registry_entries(self._unstack(self._states.registry)[shard])
 
     # ------------------------------------------------------ balance surface
+    def _unstack(self, stacked) -> list:
+        """Per-shard host views of a stacked device tree, pulled as one
+        packed array (``_packer``)."""
+        leaves, treedef = self._jax.tree_util.tree_flatten(stacked)
+        flat = np.asarray(_packer(self._sharding)(stacked))
+        host, at = [], 0
+        for leaf in leaves:
+            n = int(np.prod(leaf.shape[1:]))
+            part = flat[:, at:at + n]
+            at += n
+            part = (part.astype(bool) if leaf.dtype == bool
+                    else part.view(leaf.dtype))
+            host.append(part.reshape(leaf.shape))
+        host = treedef.unflatten(host)
+        tree_map = self._jax.tree_util.tree_map
+        return [tree_map(lambda x, s=s: x[s], host) for s in range(self.n)]
+
     @property
     def states(self):
         if self._host_states is None:
-            tree_map = self._jax.tree_util.tree_map
-            host = tree_map(np.asarray, self._states)
-            self._host_states = [
-                tree_map(lambda x, s=s: x[s], host) for s in range(self.n)]
+            self._host_states = self._unstack(self._states)
         return self._host_states
 
     @property
     def bgs(self):
-        tree_map = self._jax.tree_util.tree_map
-        host = tree_map(np.asarray, self._bgs)
-        return [tree_map(lambda x, s=s: x[s], host) for s in range(self.n)]
+        if self._host_bgs is None:
+            self._host_bgs = self._unstack(self._bgs)
+        return self._host_bgs
 
     def sublists(self, s: int):
         return state_sublists(self.cfg, self.states, s)
@@ -849,17 +940,17 @@ class ShardMapBackend:
         return items[len(items) // 2][1]
 
     def _queue_bg(self, s: int, fn, cmd: int, *args) -> bool:
-        tree_map = self._jax.tree_util.tree_map
-        bg = tree_map(lambda x: x[s], self._bgs)
-        bg, ok = fn(bg, *args)
-        self._bgs = tree_map(lambda col, leaf: col.at[s].set(leaf),
-                             self._bgs, bg)
+        self._bgs, ok = _slot_command(fn, None, self._sharding)(
+            self._bgs, s, *args)
+        ok = bool(ok)
+        if ok:          # a refused command leaves the table as it was
+            self._host_bgs = None
+            self._bg_busy = True
         if self.durability is not None:
             # host-side BgTable mutation bypasses the inbox — journal it
             # so WAL replay re-queues the command (wal.py KIND_COMMAND)
-            self.durability.log_command(s, self.round_no, cmd, args,
-                                        bool(ok))
-        return bool(ok)
+            self.durability.log_command(s, self.round_no, cmd, args, ok)
+        return ok
 
     def split(self, s, entry_keymax, sitem_idx) -> bool:
         return self._queue_bg(s, B.queue_split, wal.CMD_SPLIT,
@@ -877,13 +968,10 @@ class ShardMapBackend:
     def _queue_state(self, s: int, fn, cmd: int, *args) -> bool:
         """Like ``_queue_bg`` but for commands that edit ``ShardState``
         (the replication session table) instead of the BgTable."""
-        tree_map = self._jax.tree_util.tree_map
-        st = tree_map(lambda x: x[s], self._states)
-        st, ok = fn(st, self.cfg, *args)
-        self._states = tree_map(lambda col, leaf: col.at[s].set(leaf),
-                                self._states, st)
+        self._states, ok = _slot_command(fn, self.cfg, self._sharding)(
+            self._states, s, *args)
         self._host_states = None
-        ok = bool(np.asarray(ok))
+        ok = bool(ok)
         if self.durability is not None:
             self.durability.log_command(s, self.round_no, cmd, args, ok)
         return ok

@@ -172,9 +172,8 @@ class Balancer:
             mean = total / max(len(targets), 1)
 
             # per-shard slot budget + per-entry claims of in-flight ops; both
-            # are maintained locally as commands are issued this pass. Snapshot
-            # ``cl.bgs`` once: on ShardMapBackend every access pulls the whole
-            # stacked table device-to-host
+            # are maintained locally as commands are issued this pass, over
+            # one snapshot of ``cl.bgs`` taken before the first command
             bgs = cl.bgs
             free = {s: B.free_slots(bgs[s]) for s in routable}
             claimed = {s: B.claimed_keys(bgs[s]) for s in routable}

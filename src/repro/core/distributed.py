@@ -21,15 +21,16 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import bg as B
 from . import messages as M
-from .shard import COUNTERS, shard_round
+from .shard import shard_round
 from .types import DiLiConfig, ShardState
 
 AXIS = "shard"
-# make_dili_round's stats row: the shard-round's COUNTERS, then these
-# three counts over the rows the all_to_all routed to the shard
-ROUTED_LIVE = len(COUNTERS)         # live rows (quiescence signal)
-ROUTED_OPS = len(COUNTERS) + 1      # delegated MSG_OP rows
-ROUTED_HOPS = len(COUNTERS) + 2     # max delegation-hop count among them
+# make_dili_round's harvest row: the shard-round's packed ``RoundOut.
+# harvest`` (``shard.Harvest`` layout), then these three counts over the
+# rows the all_to_all routed to the shard, at these negative offsets
+ROUTED_LIVE = -3    # live rows (quiescence signal)
+ROUTED_OPS = -2     # delegated MSG_OP rows
+ROUTED_HOPS = -1    # max delegation-hop count among them
 
 
 def bucket_by_dst(outbox, count, num_shards: int, cap_pair: int):
@@ -58,24 +59,20 @@ def bucket_by_dst(outbox, count, num_shards: int, cap_pair: int):
 
 def make_dili_round(mesh: Mesh, cfg: DiLiConfig, cap_pair: int = 8):
     """Build the jitted SPMD round: (states, bgs, inbox, client) ->
-    (states, bgs, inbox_next, comp_slot, comp_val, comp_src, comp_key,
-    stats).
+    (states, bgs, inbox_next, harvest).
 
     All arguments are stacked over the leading shard axis and sharded over
-    the mesh's flattened device axes. ``comp_src`` is the shard that
-    executed each completed op (route-correction feedback for the client
-    API); ``comp_key`` tags completion rows — SH_KEY for scalar results,
-    a real key for RANGE items (DESIGN.md §16; the routed inbox never
-    crosses to the host on this path, so the completion lanes are the
-    only channel scan items can ride). ``stats`` is int32 per shard,
-    computed on-device so the host driver never pulls the routed inbox:
-    the shard-round's ``COUNTERS`` (``out_count`` first, which detects
-    ``bucket_by_dst`` overflow instead of silently losing rows), then at
-    ``ROUTED_LIVE``/``ROUTED_OPS``/``ROUTED_HOPS`` the live rows, the
-    delegated MSG_OP rows and their max hop count routed to the shard.
-
-    The trailing ``ent_hits`` output is int32[S, M]: per-entry op
-    attribution this round (the balancer's op-rate EWMA feed).
+    the mesh's flattened device axes. ``harvest`` is int32 ``[S, H + 3]``:
+    each shard-round's packed ``RoundOut.harvest`` (counters, ``ent_hits``,
+    registry keymax, outbox, the four completion columns; read with
+    ``shard.unpack_harvest``), then at ``ROUTED_LIVE``/``ROUTED_OPS``/
+    ``ROUTED_HOPS`` the live rows, the delegated MSG_OP rows and their max
+    hop count routed to the shard — everything the host driver reads of a
+    round, in one array, so the routed inbox never crosses to the host.
+    The completion columns carry RANGE items too (``comp_key`` a real
+    key; DESIGN.md §16): on this path they are the only channel scan
+    items can ride. The counters' ``out_count`` detects ``bucket_by_dst``
+    overflow instead of silently losing rows.
     """
     num = cfg.num_shards
     assert num == mesh.devices.size, (num, mesh.devices.size)
@@ -100,7 +97,7 @@ def make_dili_round(mesh: Mesh, cfg: DiLiConfig, cap_pair: int = 8):
         rows = inbox_next[0]
         live = rows[:, M.F_KIND] != M.MSG_NONE
         is_op = rows[:, M.F_KIND] == M.MSG_OP
-        stats = jnp.concatenate([out.counters, jnp.stack([
+        harvest = jnp.concatenate([out.harvest, jnp.stack([
             jnp.sum(live).astype(jnp.int32),
             jnp.sum(is_op).astype(jnp.int32),
             jnp.max(jnp.where(is_op, rows[:, M.F_X2], 0)).astype(jnp.int32),
@@ -108,18 +105,14 @@ def make_dili_round(mesh: Mesh, cfg: DiLiConfig, cap_pair: int = 8):
         add1 = lambda x: x[None]
         return (jax.tree_util.tree_map(add1, out.state),
                 jax.tree_util.tree_map(add1, out.bg),
-                inbox_next,
-                out.comp_slot[None], out.comp_val[None],
-                out.comp_src[None], out.comp_key[None], stats[None],
-                out.ent_hits[None])
+                inbox_next, harvest[None])
 
     pspec = P(axes)
 
     fn = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(pspec, pspec, pspec, pspec),
-        out_specs=(pspec, pspec, pspec, pspec, pspec, pspec, pspec,
-                   pspec, pspec),
+        out_specs=(pspec, pspec, pspec, pspec),
         check_vma=False)
     return jax.jit(fn)
 
@@ -130,13 +123,10 @@ def make_dili_round_hostroute(mesh: Mesh, cfg: DiLiConfig):
     nemesis-enabled path — the adversary lives on the wire between
     outboxes and inboxes, so routing must cross the host).
 
-    (states, bgs, inbox, client) ->
-        (states, bgs, outbox, comp_slot, comp_val, comp_src, comp_key,
-         stats)
+    (states, bgs, inbox, client) -> (states, bgs, harvest)
 
-    ``outbox`` is the raw [S, mailbox_cap, FIELDS] per-shard outbox;
-    ``stats`` is the shard-round's ``COUNTERS`` per shard; the trailing
-    ``ent_hits`` output is int32[S, M] per-entry op attribution.
+    ``harvest`` is each shard-round's packed ``RoundOut.harvest`` stacked
+    ``[S, H]`` (``shard.unpack_harvest``); the raw outboxes ride in it.
     Delegation stats (hops) are computed host-side from the outbox rows
     themselves — the host sees every frame on this path.
     """
@@ -151,18 +141,13 @@ def make_dili_round_hostroute(mesh: Mesh, cfg: DiLiConfig):
         out = shard_round(state, bg, me, inbox[0], client[0], cfg)
         add1 = lambda x: x[None]
         return (jax.tree_util.tree_map(add1, out.state),
-                jax.tree_util.tree_map(add1, out.bg),
-                out.outbox[None],
-                out.comp_slot[None], out.comp_val[None],
-                out.comp_src[None], out.comp_key[None], out.counters[None],
-                out.ent_hits[None])
+                jax.tree_util.tree_map(add1, out.bg), out.harvest[None])
 
     pspec = P(axes)
     fn = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(pspec, pspec, pspec, pspec),
-        out_specs=(pspec, pspec, pspec, pspec, pspec, pspec, pspec,
-                   pspec, pspec),
+        out_specs=(pspec, pspec, pspec),
         check_vma=False)
     return jax.jit(fn)
 

@@ -93,9 +93,11 @@ _SUMMED = ("fast_hits", "mut_hits", "move_hits", "blk_hits", "rep_hits",
 
 
 class Harvest(NamedTuple):
-    """What ``Cluster.step`` reads of one shard-round, as ``RoundOut.
-    harvest`` packs it: these fields of ``RoundOut`` (``keymax`` is the
-    post-round ``state.registry.keymax``), flattened in this order."""
+    """What a host driver (``Cluster.step``, ``ShardMapBackend.step``)
+    reads of one shard-round, as ``RoundOut.harvest`` packs it: these
+    fields of ``RoundOut`` (``keymax`` is the post-round
+    ``state.registry.keymax``), flattened in this order. Shapes are one
+    shard's; a stacked harvest adds a leading shard axis to each."""
     counters: np.ndarray     # [len(COUNTERS)]
     ent_hits: np.ndarray     # [M]
     keymax: np.ndarray       # [M]
@@ -107,16 +109,18 @@ class Harvest(NamedTuple):
 
 
 def unpack_harvest(vec: np.ndarray, cfg: DiLiConfig) -> Harvest:
-    """Split a pulled ``RoundOut.harvest`` into its fields (views)."""
+    """Split a pulled ``RoundOut.harvest`` into its fields (views): one
+    shard's vector ``[H]``, or shards' vectors stacked ``[S, H]``."""
     m = cfg.max_sublists
     fixed = len(COUNTERS) + 2 * m + cfg.mailbox_cap * M.FIELDS
-    k = (vec.shape[0] - fixed) // 4
+    k = (vec.shape[-1] - fixed) // 4
+    lead = vec.shape[:-1]
     shapes = ((len(COUNTERS),), (m,), (m,), (cfg.mailbox_cap, M.FIELDS),
               (k,), (k,), (k,), (k,))
     parts, at = [], 0
     for shape in shapes:
         size = int(np.prod(shape))
-        parts.append(vec[at:at + size].reshape(shape))
+        parts.append(vec[..., at:at + size].reshape(lead + shape))
         at += size
     return Harvest(*parts)
 

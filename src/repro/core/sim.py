@@ -226,11 +226,10 @@ def global_keys(cfg: DiLiConfig, states: Sequence[ShardState]) -> List[int]:
     return sorted(keys)
 
 
-def registry_entries(state: ShardState):
-    """One shard's registry replica as (keymin, keymax, owner) triples,
-    sorted by keymin — the view a client seeds/refreshes its route cache
-    from (DESIGN.md §9)."""
-    reg = state.registry
+def registry_entries(reg):
+    """One shard's registry replica (``ShardState.registry``) as (keymin,
+    keymax, owner) triples, sorted by keymin — the view a client
+    seeds/refreshes its route cache from (DESIGN.md §9)."""
     size = int(reg.size)
     kmin = np.asarray(reg.keymin)[:size]
     kmax = np.asarray(reg.keymax)[:size]
@@ -855,7 +854,7 @@ class Cluster:
 
     def registry_entries(self, s: int = 0):
         """Shard ``s``'s registry replica as (keymin, keymax, owner)."""
-        return registry_entries(self.states[s])
+        return registry_entries(self.states[s].registry)
 
     # ---------------------------------------------------------- bg commands
     # Each returns True if a slot accepted the command, False if it was

@@ -20,7 +20,9 @@ SCRIPT = textwrap.dedent("""
 
     from repro.core import messages as M
     from repro.core import background as B
-    from repro.core.distributed import make_dili_round, stack_states
+    from repro.core.distributed import (ROUTED_LIVE, make_dili_round,
+                                        stack_states)
+    from repro.core.shard import unpack_harvest
     from repro.core.oracle import OracleList
     from repro.core.sim import Cluster
     from repro.core.types import (DiLiConfig, OP_FIND, OP_INSERT, OP_REMOVE,
@@ -69,9 +71,9 @@ SCRIPT = textwrap.dedent("""
     zeros = jnp.zeros((4, cfg.batch_size, M.FIELDS), jnp.int32)
     for r in range(38):
         batch = client_batch(r) if r < 30 else zeros  # 8 drain rounds
-        states, bgs, inbox, cs, cv, _csrc, _ckey, _cnt, _hits = rnd(
-            states, bgs, inbox, batch)
-        cs, cv = np.asarray(cs), np.asarray(cv)
+        states, bgs, inbox, vec = rnd(states, bgs, inbox, batch)
+        h = unpack_harvest(np.asarray(vec)[:, :ROUTED_LIVE], cfg)
+        cs, cv = h.comp_slot, h.comp_val
         for s in range(4):
             for a, b in zip(cs[s], cv[s]):
                 if a >= 0:

@@ -139,3 +139,13 @@ def test_spmd_round_compiles_for_v5e_2x2(topo, monkeypatch):
     text = rnd.lower(state, bg, inbox, client).compile().as_text()
     assert "tpu_custom_call" in text
     assert "all-to-all" in text
+    # everything the host reads of the round is one stacked int32 array:
+    # each shard's packed harvest, then the three routed counts
+    one = jax.eval_shape(
+        lambda: shard_round(init_shard(cfg, 0), B.init_bg_table(cfg), 0,
+                            jnp.zeros((in_cap, M.FIELDS), jnp.int32),
+                            jnp.zeros((cfg.batch_size, M.FIELDS),
+                                      jnp.int32), cfg))
+    harvest = jax.eval_shape(rnd, state, bg, inbox, client)[-1]
+    assert harvest.shape == (cfg.num_shards, one.harvest.shape[0] + 3)
+    assert harvest.dtype == jnp.int32
